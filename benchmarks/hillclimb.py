@@ -121,8 +121,9 @@ def p2m_block_hillclimb() -> None:
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu:
         matmul_sigs = [(112 * 112, 75, 8), (8 * 112 * 112, 75, 8)]
-        conv_sigs = [(1, 224, 224, 3, 8, 5, 5), (8, 224, 224, 3, 8, 5, 5),
-                     (1, 224, 224, 3, 8, 5, 2)]
+        # paper geometry (560², k = s = 5); stride != kernel does not
+        # compile for TPU (`conv.mosaic_conv_error`)
+        conv_sigs = [(1, 560, 560, 3, 8, 5, 5), (8, 560, 560, 3, 8, 5, 5)]
     else:  # interpret mode: toy shapes, machinery-only
         matmul_sigs = [(256, 75, 8)]
         conv_sigs = [(1, 20, 20, 3, 8, 5, 5)]
@@ -159,6 +160,9 @@ def term_summary(rec: dict) -> dict:
 def main() -> None:
     import sys as _sys
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if "--p2m-blocks" in _sys.argv:
         p2m_block_hillclimb()
         return

@@ -3,7 +3,7 @@ collective terms per (arch × shape × mesh) from the dry-run artifacts.
 
     compute_s   = HLO_FLOPs_per_device / peak_FLOPs        (bf16 MXU)
     memory_s    = HLO_bytes_per_device / HBM_bw
-    collective_s = collective_bytes_per_device / ICI_link_bw
+    collective_s = collective_bytes_per_device / ICI_bw_per_chip
 
 (`cost_analysis` numbers are per-partition for SPMD modules — verified
 against a hand-counted sharded matmul — so dividing by per-chip peaks is
@@ -20,7 +20,7 @@ import json
 from pathlib import Path
 
 from repro.configs import SHAPES, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, chip_peaks
 
 RESULTS = Path(__file__).resolve().parent / "results"
 DRYRUN = RESULTS / "dryrun"
@@ -83,12 +83,13 @@ def analyze_record(r: dict) -> dict | None:
     flops_dev = r.get("flops_per_device", 0.0)
     bytes_dev = r.get("bytes_per_device", 0.0)
     coll_dev = r.get("collectives", {}).get("total_bytes", 0)
-    compute_s = flops_dev / PEAK_FLOPS_BF16
-    memory_hlo_s = bytes_dev / HBM_BW  # unfused upper bound (CPU-compiled HLO)
+    peaks = chip_peaks(PRODUCTION_DEVICE_KIND)
+    compute_s = flops_dev / peaks.flops_bf16
+    memory_hlo_s = bytes_dev / peaks.hbm_bytes_per_s  # unfused upper bound (CPU-compiled HLO)
     floor_bytes = analytic_memory_floor(r["arch"], r["shape"],
                                         r.get("mesh_shape", {}))
-    memory_s = floor_bytes / HBM_BW  # perfect-fusion floor (TPU-realistic)
-    collective_s = coll_dev / ICI_BW
+    memory_s = floor_bytes / peaks.hbm_bytes_per_s  # perfect-fusion floor (TPU-realistic)
+    collective_s = coll_dev / peaks.ici_bytes_per_s
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)
@@ -99,7 +100,8 @@ def analyze_record(r: dict) -> dict | None:
     # roofline fraction: useful model compute per step over what the
     # dominant term allows at peak
     step_time = bound_s
-    mfu = (model_flops / chips / PEAK_FLOPS_BF16) / step_time if step_time else 0.0
+    mfu = ((model_flops / chips / peaks.flops_bf16) / step_time
+           if step_time else 0.0)
     return {
         "arch": r["arch"], "shape": r["shape"], "mesh": r["mesh"],
         "tag": r.get("tag", ""),
@@ -157,7 +159,8 @@ def write_report(rows: list[dict], path: Path) -> None:
         "HLO analysis); memory(floor) = analytic perfect-fusion bytes ÷ 819 GB/s; "
         "memory(hlo) = unfused-HLO bytes ÷ 819 GB/s (upper bound — the CPU "
         "backend fuses less than TPU, real traffic lands between the bounds); "
-        "collective = HLO collective operand bytes/chip ÷ 50 GB/s/link. "
+        "collective = HLO collective operand bytes/chip ÷ 200 GB/s/chip "
+        "(1,600 Gbit/s ICI). "
         "Dominance and roofline fraction use the floor.",
         "",
         "| arch | shape | mesh | compute | mem(floor) | mem(hlo) | collective "

@@ -24,18 +24,21 @@ def main():
     ap.add_argument("--multipod", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.dryrun import run_cell
-    from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND, chip_peaks
 
+    enable_compile_cache()
     rec = run_cell(args.arch, args.shape, args.multipod, force=True,
                    tag="-demo")
     if rec["status"] != "ok":
         print(rec.get("error"))
         return
     chips = rec["chips"]
-    comp = rec["flops_per_device"] / PEAK_FLOPS_BF16
-    mem = rec["bytes_per_device"] / HBM_BW
-    coll = rec["collectives"]["total_bytes"] / ICI_BW
+    peaks = chip_peaks(PRODUCTION_DEVICE_KIND)
+    comp = rec["flops_per_device"] / peaks.flops_bf16
+    mem = rec["bytes_per_device"] / peaks.hbm_bytes_per_s
+    coll = rec["collectives"]["total_bytes"] / peaks.ici_bytes_per_s
     print(f"\n{args.arch} × {args.shape} on {chips} chips:")
     print(f"  compiled in {rec['compile_s']:.1f}s "
           f"(HLO {rec['hlo_bytes']/1e6:.1f} MB)")

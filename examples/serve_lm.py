@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import SyntheticVWW
 from repro.launch.serve import FrontDoor
 from repro.models.families import get_family
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=24)
     ap.add_argument("--prefill-chunk", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch).replace(dtype=jnp.float32)
     family = get_family(cfg)

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Tracer, default_registry
 from repro.configs.p2m_vww import SERVE_MAX_BATCH, SERVE_MAX_QUEUE
 from repro.data import SyntheticVWW
@@ -77,6 +78,7 @@ def main():
                     help="export a Perfetto tick-domain trace of the "
                          "replay to this path (DESIGN.md §13)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = MNV2Config(variant="p2m", image_size=args.image_size, width=0.25,
                      head_channels=64)

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.bandwidth import bandwidth_reduction
 from repro.data import SyntheticVWW
 from repro.launch.mesh import make_debug_mesh
@@ -61,6 +62,7 @@ def main():
     ap.add_argument("--mesh", action="store_true",
                     help="shard the stream microbatch over all devices")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = MNV2Config(variant="p2m", image_size=args.image_size, width=0.25,
                      head_channels=64)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bn_fold import deploy_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.quant import QuantSpec, quantize_deploy
 from repro.data import SyntheticVWW
 from repro.models.mobilenetv2 import MNV2Config, apply_mnv2, init_mnv2
@@ -65,6 +66,7 @@ def main():
     ap.add_argument("--sweep", action="store_true",
                     help="Fig. 7a: output bit-precision sweep after training")
     args = ap.parse_args()
+    enable_compile_cache()
 
     base_cfg = MNV2Config(variant="baseline", image_size=args.image_size,
                           width=args.width, head_channels=64)

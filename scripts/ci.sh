@@ -54,25 +54,23 @@ echo "== bench regression gate (vs BENCH_p2m_conv.json baseline) =="
 # events, no orphaned request tracks, monotone tick stamps)
 python scripts/bench_gate.py
 
-echo "== accelerator lane (opt-in: active when jax reports tpu/gpu) =="
-# On a real accelerator the kernel tests re-run with
-# REPRO_P2M_NO_INTERPRET=1 — the pipelined/gated kernel tests read it
-# and drop their interpret=True pins, compiling the kernels for real —
-# and the bench smoke re-runs compiled, emitting same-backend rows next
-# to the committed CPU ones (bench_gate only compares same-backend
-# pairs, so the lanes never gate against each other).  On CPU-only
-# machines this lane is a no-op by design.
+echo "== chip lane (active when jax reports a tpu) =="
+# On a TPU host the kernel tests re-run with their kernels compiled (off
+# the TPU they run interpreted; compiled, a geometry Mosaic cannot lower
+# must raise ValueError instead).  Their XLA references are fp32 tolerance
+# checks, so they run at highest matmul precision, as on the CPU; the
+# TPU default is one bf16 pass.  Then the chip smoke drives the paper's
+# configuration through serving, streaming and training on compiled
+# kernels (chip_smoke.py).  Each step is one process that exits before
+# the next starts: one process per chip.
 BACKEND="$(python -c 'import jax; print(jax.default_backend())')"
-if [ "$BACKEND" = "tpu" ] || [ "$BACKEND" = "gpu" ]; then
-  echo "accelerator backend: $BACKEND — running non-interpret kernel lane"
-  REPRO_P2M_NO_INTERPRET=1 python -m pytest -x -q \
+if [ "$BACKEND" = "tpu" ]; then
+  JAX_DEFAULT_MATMUL_PRECISION=highest python -m pytest -x -q \
     tests/test_p2m_kernel.py tests/test_p2m_conv_fused.py \
     tests/test_p2m_conv_pipelined.py
-  python benchmarks/run.py --smoke
-  python scripts/bench_gate.py
+  python chip_smoke.py
 else
-  echo "accelerator lane: skipped (backend=$BACKEND; set up a TPU/GPU"
-  echo "  runtime to exercise the compiled kernel path)"
+  echo "chip lane: skipped (backend=$BACKEND)"
 fi
 
 echo "verify: OK"

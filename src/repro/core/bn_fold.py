@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.core.p2m_conv import P2MConvConfig, _flat_weights
 from repro.core.pixel_model import PixelModel
-from repro.kernels.p2m_conv.ops import p2m_matmul_jnp
+from repro.kernels.p2m_conv import ops  # module reference: see core/p2m_conv.py
 
 
 def bn_affine(gamma, beta, mean, var, eps: float = 1e-5):
@@ -64,10 +64,10 @@ def fold_error(
     )
     w = _flat_weights(params["theta"], cfg)
     zero = jnp.zeros((cfg.out_channels,), jnp.float32)
-    raw = p2m_matmul_jnp(sample_patches, w, zero, model, cfg.adc, mode="raw")
+    raw = ops.p2m_matmul_jnp(sample_patches, w, zero, model, cfg.adc, mode="raw")
     exact = a[None, :] * raw + b[None, :]
     w_fold = jnp.clip(w * a[None, :], -1.0, 1.0)
-    folded = p2m_matmul_jnp(sample_patches, w_fold, b, model, cfg.adc, mode="raw")
+    folded = ops.p2m_matmul_jnp(sample_patches, w_fold, b, model, cfg.adc, mode="raw")
     return float(jnp.max(jnp.abs(exact - folded)))
 
 

@@ -27,7 +27,11 @@ import jax.numpy as jnp
 
 from repro.core.adc import ADCConfig
 from repro.core.pixel_model import PixelModel, default_pixel_model
-from repro.kernels.p2m_conv.ops import p2m_conv, p2m_conv_jnp, p2m_matmul_jnp
+# A module reference, not a from-import: `ops` imports `repro.core.adc`,
+# which runs this package's __init__ and so this module, before `ops` has
+# finished when `ops` is imported first.
+from repro.kernels.p2m_conv import ops
+from repro.parallel.axes import batch_shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,17 +129,19 @@ def _conv_raw(images, w, cfg: P2MConvConfig, model: PixelModel,
     """Pre-epilogue conv accumulation (B, Ho, Wo, Co) via the chosen impl."""
     zero = jnp.zeros((cfg.out_channels,), jnp.float32)
     if impl == "pallas":
-        return p2m_conv(images, w, zero, model, cfg.adc, "raw",
-                        cfg.kernel, cfg.stride)
+        return batch_shard_map(
+            lambda im, w_, sh: ops.p2m_conv(im, w_, sh, model, cfg.adc, "raw",
+                                            cfg.kernel, cfg.stride),
+            images, w, zero)
     if impl == "fused":
-        return p2m_conv_jnp(images, w, zero, model, cfg.adc, "raw",
-                            cfg.kernel, cfg.stride)
+        return ops.p2m_conv_jnp(images, w, zero, model, cfg.adc, "raw",
+                                cfg.kernel, cfg.stride)
     b = images.shape[0]
     ho = cfg.out_spatial(images.shape[1])
     wo = cfg.out_spatial(images.shape[2])
     patches = extract_patches(images, cfg.kernel, cfg.stride)  # (B,P,K)
     xf = patches.reshape(b * patches.shape[1], -1)
-    raw = p2m_matmul_jnp(xf, w, zero, model, cfg.adc, mode="raw")
+    raw = ops.p2m_matmul_jnp(xf, w, zero, model, cfg.adc, mode="raw")
     return raw.reshape(b, ho, wo, cfg.out_channels)
 
 
@@ -154,7 +160,9 @@ def apply_p2m_conv_train(
 
     ``impl`` selects the conv path (see `_resolve_impl`); the default is
     the fused implicit-im2col kernel on TPU and its XLA twin elsewhere,
-    with ``"patches"`` as the materializing reference fallback.
+    with ``"patches"`` as the materializing reference fallback.  Under a
+    sharding plan the kernel runs once per batch shard
+    (`parallel.batch_shard_map`): XLA cannot partition a Mosaic kernel.
 
     Returns ``(out (B, Ho, Wo, Co), new_state)``.
     """
@@ -210,16 +218,18 @@ def apply_p2m_conv_deploy(
         impl = "patches"
     impl = _resolve_impl(impl)
     if impl == "pallas":
-        return p2m_conv(images, deploy["w"], deploy["shift"], model,
-                        cfg.adc, mode, cfg.kernel, cfg.stride)
+        return batch_shard_map(
+            lambda im, w, sh: ops.p2m_conv(im, w, sh, model, cfg.adc, mode,
+                                           cfg.kernel, cfg.stride),
+            images, deploy["w"], deploy["shift"])
     if impl == "fused":
-        return p2m_conv_jnp(images, deploy["w"], deploy["shift"], model,
-                            cfg.adc, mode, cfg.kernel, cfg.stride)
+        return ops.p2m_conv_jnp(images, deploy["w"], deploy["shift"], model,
+                                cfg.adc, mode, cfg.kernel, cfg.stride)
     b = images.shape[0]
     ho = cfg.out_spatial(images.shape[1])
     wo = cfg.out_spatial(images.shape[2])
     patches = extract_patches(images, cfg.kernel, cfg.stride)
     xf = patches.reshape(b * patches.shape[1], -1)
-    out = p2m_matmul_jnp(xf, deploy["w"], deploy["shift"], model, cfg.adc,
-                         mode=mode)
+    out = ops.p2m_matmul_jnp(xf, deploy["w"], deploy["shift"], model,
+                             cfg.adc, mode=mode)
     return out.reshape(b, ho, wo, cfg.out_channels)

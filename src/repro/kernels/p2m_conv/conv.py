@@ -106,6 +106,20 @@ def _epilogue_values(raw, shift, *, mode: str, v_lsb: float, max_count: int):
     raise ValueError(f"unknown mode {mode!r}")
 
 
+def _tile_dot(xcat, wmix2d):
+    """``[x, x², …] @ W̃`` at full fp32 precision, whatever the caller's
+    matmul-precision context.  The epilogue rounds this sum to ADC counts,
+    and Mosaic's default (one bf16 pass) put 17% of the paper-geometry
+    counts up to 3 LSB off the fp32 pixel model on a TPU v5e."""
+    return jax.lax.dot_general(
+        xcat,
+        wmix2d.astype(jnp.float32),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _accumulate_step(x2d, wmix2d, acc_ref, *, dx: int, first: jax.Array):
     """One grid step: acc += [x, x², …] @ W̃-tile (single MXU dot)."""
 
@@ -114,12 +128,7 @@ def _accumulate_step(x2d, wmix2d, acc_ref, *, dx: int, first: jax.Array):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     xcat = _power_concat(x2d.astype(jnp.float32), dx)
-    acc_ref[...] += jax.lax.dot_general(
-        xcat,
-        wmix2d.astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _tile_dot(xcat, wmix2d)
 
 
 def _write_outputs(shift_ref, out_ref, raw_ref, acc_ref, *, last, mode,
@@ -216,12 +225,7 @@ def _pipelined_body(x_tile_2d, wbuf, shift_ref, out_ref, raw_ref, *, k: int,
         x_dma(slot, ki).wait()
         w_dma(slot, ki).wait()
         xcat = _power_concat(x_tile_2d(slot).astype(jnp.float32), dx)
-        term = jax.lax.dot_general(
-            xcat,
-            wbuf[slot].astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        term = _tile_dot(xcat, wbuf[slot])
         # Same fp-add order as the grid path's (init-zeros, then +=).
         acc = term if ki == 0 else acc + term
         nxt = ki + nbuf
@@ -300,6 +304,34 @@ def _conv_kernel_general_pipelined(rows_hbm, wmix_hbm, shift_ref, *refs,
 
 
 
+def mosaic_conv_error(kernel: int, stride: int, channels: int,
+                      pipeline_depth: int = 0) -> str | None:
+    """Why the compiled (Mosaic) TPU lowering rejects this conv, or None.
+
+    Interpret mode runs every path; Mosaic refuses two of them:
+
+    * ``stride != kernel`` — the general path gathers its k sliding
+      windows with a ``(bh, Wo, k, C) → (bh·Wo, k·C)`` in-register reshape
+      ("infer-vector-layout: unsupported shape cast");
+    * ``pipeline_depth >= 2`` with ``k·C`` off the 128-lane quantum — the
+      DMA ring slices ``(bh, Wo, k·C)`` tiles out of HBM, and a DMA slice's
+      lane dim must be a multiple of 128 ("Slice shape along dimension 3
+      must be aligned to tiling (128)"); ``k·C`` is 15 at the paper's
+      geometry.
+    """
+    if stride != kernel:
+        return (f"stride {stride} != kernel {kernel}: the general strided "
+                "P2M conv does not lower with Mosaic (its window gather is "
+                "an unsupported in-register reshape); only stride == kernel "
+                "compiles for TPU — use impl='fused' for other strides")
+    kc = kernel * channels
+    if pipeline_depth >= 2 and kc % 128:
+        return (f"pipeline_depth {pipeline_depth} needs kernel*channels "
+                f"(= {kc}) to be a multiple of 128: the DMA ring's HBM tile "
+                "slices must be lane-aligned; use pipeline_depth=0")
+    return None
+
+
 def default_conv_blocks(b: int, ho: int, wo: int, n: int,
                         kc_dx: int) -> tuple[int, int]:
     """(block_h, block_n) heuristic: bh·Wo ≈ 2048 rows per tile, full-N
@@ -347,6 +379,10 @@ def p2m_conv_pallas(
     while the current tile is on the MXU (DESIGN.md §3.5) — an autotuner
     axis (`tune.py`).  Outputs are bitwise-identical either way.
 
+    Compiled (``interpret=False``), a geometry Mosaic cannot lower raises
+    ``ValueError`` naming the cause (`mosaic_conv_error`): ``stride !=
+    kernel``, and the ring wherever ``k·C`` is off the 128-lane quantum.
+
     VMEM per step (fp32 words): x-tile ``bh·Wo·dx·kC`` (power concat) +
     W̃-tile ``dx·kC·bn`` + acc/out ``2·bh·Wo·bn``.  At the paper geometry
     (Wo=112, kC=75, dx=3, bh=8, bn=128) that is ≈ 1.3 MB — double-buffered
@@ -358,6 +394,8 @@ def p2m_conv_pallas(
                          f"(double-buffered ring), got {pipeline_depth}")
     b, h, w_dim, c = images.shape
     k, s = kernel, stride
+    if not interpret and (err := mosaic_conv_error(k, s, c, pipeline_depth)):
+        raise ValueError(err)
     ho = conv_out_spatial(h, k, s)
     wo = conv_out_spatial(w_dim, k, s)
     kc = k * c
@@ -439,8 +477,8 @@ def p2m_conv_pallas(
             kernel_fn,
             grid=grid,
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec((1, bn), lambda mi, ni: (0, ni)),
             ],
             out_specs=out_specs,

@@ -51,6 +51,7 @@ from repro.kernels.p2m_conv.conv import (
     ceil_to,
     conv_out_spatial,
     default_conv_blocks,
+    mosaic_conv_error,
     p2m_conv_jnp,
     premix_weights,
 )
@@ -161,10 +162,13 @@ def p2m_conv_pallas_gated(
     images: (B, H, W, C); w/shift as `p2m_conv_pallas`; cached:
     (B, Ho, Wo, N) — the slot-resident stem cache; rerun: (B,) bool.
     Inference-only (no VJP): the serving hot path never differentiates
-    through the gate.
+    through the gate.  Compiled, ``stride != kernel`` raises ``ValueError``
+    (`conv.mosaic_conv_error`).
     """
     b, h, w_dim, c = images.shape
     k, s = kernel, stride
+    if not interpret and (err := mosaic_conv_error(k, s, c)):
+        raise ValueError(err)
     ho = conv_out_spatial(h, k, s)
     wo = conv_out_spatial(w_dim, k, s)
     kc = k * c

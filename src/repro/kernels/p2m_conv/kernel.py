@@ -50,8 +50,10 @@ def _p2m_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]
-    w = w_ref[...]
+    # powers and sign in fp32: a v5e's VPU has no bf16 compare (Mosaic:
+    # "Target does not support this comparison")
+    x = x_ref[...].astype(jnp.float32)
+    w = w_ref[...].astype(jnp.float32)
     sgn = jnp.sign(w)
     aw = jnp.abs(w)
 

@@ -17,7 +17,9 @@ Tiers, all computing the same math (see `ref.py` for the oracle):
 Forward Pallas calls route their block sizes through the autotuner
 (`tune.py`; off-TPU it returns the static defaults instantly), and the
 backward kernels reuse the forward winner for the same (M, K, N)
-signature — the tile dims are driven by the same operands.
+signature — the tile dims are driven by the same operands.  A call on
+Tracers (inside jit or grad) only looks winners up: the tuner times
+eager calls alone.
 """
 from __future__ import annotations
 

@@ -17,6 +17,17 @@ once per process; every later call is a dict lookup, so the tuner adds
 one-off JIT-warmup-style latency, never steady-state cost.  The cache can
 be exported as JSON (`cache_dump`) so benchmark runs can record winners.
 
+Timing happens only in an **eager** call on concrete arrays (as
+`benchmarks/hillclimb.py` makes them).  A call made while JAX traces
+(jit, grad, the serving and training programs) serves the cached winner
+or the static default and counts the default as
+``autotune.traced_default``, and `autotune` itself refuses to run there
+— a kernel launched under a trace only stages, so timing it would time
+tracing.  Candidates the compiled (Mosaic) lowering rejects for the
+shape (`conv.mosaic_conv_error`) are never on the menu, and a candidate
+that fails while being timed is recorded in the decision and never
+served.
+
 Autotuning is **off by default off-TPU** (timing interpret-mode kernels
 would measure the Python interpreter): `get_*_blocks` then returns the
 static heuristic defaults instantly, and emits a one-time structured log
@@ -167,6 +178,12 @@ def conv_candidates(b: int, ho: int, wo: int, n: int, kc: int, *, dx: int = 3,
 # ---------------------------------------------------------------------------
 
 
+def _under_trace() -> bool:
+    """True while JAX traces (jit, grad, vmap): a kernel call there only
+    stages, so the clock would time tracing."""
+    return not jax.core.trace_ctx.is_top_level()
+
+
 def _time_once(fn: Callable, *args, iters: int = 3, warmup: int = 1) -> float:
     """Median wall-clock seconds, blocking on outputs."""
     for _ in range(warmup):
@@ -184,14 +201,31 @@ def _coeff_sig(coeffs) -> tuple:
     return tuple(tuple(float(v) for v in row) for row in coeffs)
 
 
+def _lookup(kind: str, key: tuple, default, *, enable: bool | None):
+    """``(blocks, False)`` when the answer needs no timing — a cached
+    winner, or the default under a trace or with tuning disabled —
+    ``(None, True)`` when the caller should tune."""
+    if key in _CACHE:
+        default_registry().counter("autotune.cache_hit").inc()
+        return _CACHE[key]["best"], False
+    if _under_trace():
+        default_registry().counter("autotune.traced_default").inc()
+        return default, False
+    if not enabled(enable):
+        _log_disabled_defaults(kind, jax.default_backend(), default)
+        return default, False
+    return None, True
+
+
 def autotune(key: tuple, candidates: Iterable, run: Callable,
              *, iters: int = 3, vmem: Callable | None = None) -> dict:
     """Generic: time `run(candidate)` for each candidate, cache the winner.
 
     Returns ``{"best": candidate, "timings": {candidate: seconds},
-    "decision": record}``.  Failures (e.g. a block shape the backend
-    rejects) are recorded as inf and skipped, so one bad candidate never
-    kills a tuning pass.
+    "decision": record}``.  A candidate that fails (e.g. a block shape the
+    backend rejects) is timed as inf, its error is kept in the decision
+    record's ``errors``, and it is never served.  Called while JAX traces
+    it raises: timing there would time tracing.
 
     Observability (DESIGN.md §13.2): every call counts
     ``autotune.cache_hit`` / ``autotune.cache_miss`` into the metrics
@@ -204,15 +238,21 @@ def autotune(key: tuple, candidates: Iterable, run: Callable,
     if key in _CACHE:
         default_registry().counter("autotune.cache_hit").inc()
         return _CACHE[key]
+    if _under_trace():
+        raise RuntimeError(f"autotune {key} called under a trace; tune only "
+                           "in an eager call on concrete arrays")
     default_registry().counter("autotune.cache_miss").inc()
     timings: dict = {}
+    errors: dict = {}
     for cand in candidates:
         try:
             timings[cand] = _time_once(run, cand, iters=iters)
-        except Exception:  # noqa: BLE001 - per-candidate isolation
+        except Exception as exc:  # noqa: BLE001 - per-candidate isolation
             timings[cand] = float("inf")
+            errors[repr(cand)] = f"{type(exc).__name__}: {exc}"[:300]
     if not timings or all(np.isinf(list(timings.values()))):
-        raise RuntimeError(f"autotune: no viable candidate for {key}")
+        raise RuntimeError(f"autotune: no viable candidate for {key}: "
+                           f"{errors}")
     best = min(timings, key=timings.get)
     decision = {
         "key": repr(key),
@@ -223,6 +263,7 @@ def autotune(key: tuple, candidates: Iterable, run: Callable,
         "best": list(best),
         "best_s": timings[best],
         "n_viable": sum(1 for t in timings.values() if np.isfinite(t)),
+        "errors": errors,
     }
     result = {"best": best, "timings": timings, "decision": decision}
     _CACHE[key] = result
@@ -242,7 +283,8 @@ def get_matmul_blocks(m: int, k: int, n: int, coeffs, mode: str,
                       *, enable: bool | None = None, interpret: bool = False,
                       iters: int = 3) -> tuple[int, int, int]:
     """(block_m, block_n, block_k) for `p2m_matmul_pallas` — tuned when
-    enabled, heuristic defaults otherwise."""
+    enabled and called eagerly, the cached winner or the heuristic
+    defaults otherwise."""
     default = (256, 128, 128)
     backend = jax.default_backend()
     # `interpret` and `backend` are part of the key: winners timed in
@@ -250,12 +292,9 @@ def get_matmul_blocks(m: int, k: int, n: int, coeffs, mode: str,
     # compiled calls with the same shape signature.
     key = ("matmul", m, k, n, _coeff_sig(coeffs), mode, bool(interpret),
            backend)
-    if key in _CACHE:
-        default_registry().counter("autotune.cache_hit").inc()
-        return _CACHE[key]["best"]
-    if not enabled(enable):
-        _log_disabled_defaults("matmul", backend, default)
-        return default
+    blocks, tune_now = _lookup("matmul", key, default, enable=enable)
+    if not tune_now:
+        return blocks
     from repro.kernels.p2m_conv.kernel import p2m_matmul_pallas
 
     rng = np.random.default_rng(0)
@@ -282,21 +321,33 @@ def get_conv_blocks(b: int, h: int, w: int, c: int, n: int, kernel: int,
                     iters: int = 3
                     ) -> tuple[int | None, int | None, int]:
     """(block_h, block_n, pipeline_depth) for `p2m_conv_pallas` — tuned
-    when enabled, ``(None, None, 0)`` otherwise (the kernel's own
-    heuristic blocks, automatic grid pipeline)."""
+    when enabled and called eagerly; otherwise the
+    cached winner or ``(None, None, 0)`` (the kernel's own heuristic
+    blocks, automatic grid pipeline).
+
+    Compiled (``interpret=False``), depths Mosaic cannot lower for this
+    shape leave the menu, and a geometry it cannot lower at all raises
+    ``ValueError`` (`conv.mosaic_conv_error`)."""
+    from repro.kernels.p2m_conv.conv import (
+        conv_out_spatial,
+        mosaic_conv_error,
+        p2m_conv_pallas,
+    )
+
     default = (None, None, 0)
+    if not interpret:
+        if err := mosaic_conv_error(kernel, stride, c):
+            raise ValueError(err)
+        depths = tuple(d for d in depths
+                       if mosaic_conv_error(kernel, stride, c, d) is None)
     backend = jax.default_backend()
     # Backend and the swept depth axis are in the key so a winner tuned on
     # one backend (or over a different depth menu) can't leak to another.
     key = ("conv", b, h, w, c, n, kernel, stride, _coeff_sig(coeffs), mode,
            bool(interpret), backend, tuple(depths))
-    if key in _CACHE:
-        default_registry().counter("autotune.cache_hit").inc()
-        return _CACHE[key]["best"]
-    if not enabled(enable):
-        _log_disabled_defaults("conv", backend, default)
-        return default
-    from repro.kernels.p2m_conv.conv import conv_out_spatial, p2m_conv_pallas
+    blocks, tune_now = _lookup("conv", key, default, enable=enable)
+    if not tune_now:
+        return blocks
 
     ho = conv_out_spatial(h, kernel, stride)
     wo = conv_out_spatial(w, kernel, stride)

@@ -48,7 +48,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
         s_scr[...] = s0_ref[0]
 
     rt, kt, vt, lwt = r_ref[0], k_ref[0], v_ref[0], lw_ref[0]  # (C, Dp)
-    u = u_ref[...]  # (1, Dp)
+    u = u_ref[0]  # (1, Dp)
     s0 = s_scr[...]  # (Dp, Dp) — running state, persists across chunks
 
     # Cumulative log decay via a lower-triangular ones matmul (Mosaic has
@@ -101,7 +101,9 @@ def wkv_pallas(r, k, v, lw, u, state, *, chunk: int = WKV_CHUNK,
                  ((0, 0), (0, dp - d), (0, dp - d)))
     # u rides per-(b,h) so the grid's flat index needs no modulo: rows
     # repeat [u_0 … u_{H-1}] per batch, matching the (B,H) flatten order.
-    uu = jnp.pad(jnp.tile(f32(u), (b, 1)), ((0, 0), (0, dp - d)))
+    # (B·H, 1, Dp) so the (1, Dp) block spans the array's last two dims —
+    # a (1, Dp) block of a 2-D (B·H, Dp) array breaks the 8-sublane rule.
+    uu = jnp.pad(jnp.tile(f32(u), (b, 1)), ((0, 0), (0, dp - d)))[:, None, :]
 
     nc = sp // chunk
     kernel = functools.partial(_wkv_kernel, chunk=chunk, nc=nc)
@@ -113,7 +115,7 @@ def wkv_pallas(r, k, v, lw, u, state, *, chunk: int = WKV_CHUNK,
             pl.BlockSpec((1, chunk, dp), lambda i, c: (i, c, 0)),  # k
             pl.BlockSpec((1, chunk, dp), lambda i, c: (i, c, 0)),  # v
             pl.BlockSpec((1, chunk, dp), lambda i, c: (i, c, 0)),  # lw
-            pl.BlockSpec((1, dp), lambda i, c: (i, 0)),            # u
+            pl.BlockSpec((1, 1, dp), lambda i, c: (i, 0, 0)),      # u
             pl.BlockSpec((1, dp, dp), lambda i, c: (i, 0, 0)),     # S_0
         ],
         out_specs=[

@@ -31,10 +31,8 @@ from pathlib import Path
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
-
 from repro.configs import ARCH_IDS, SHAPES, applicable, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import analyze as analyze_hlo
 from repro.launch.mesh import make_production_mesh, mesh_chips
 from repro.launch.specs import (
@@ -174,6 +172,7 @@ def main() -> None:
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--reanalyze", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.reanalyze:
         reanalyze()

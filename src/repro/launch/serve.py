@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.families import get_family
 from repro.serving import Request, ServeEngine, VisionRequest
 from repro.serving.scheduler import drive
@@ -283,6 +284,7 @@ def main() -> None:
                          "tick costs — cheap engines tick more often "
                          "(event-driven cadences, DESIGN.md §11)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.replace(dtype=jnp.float32)

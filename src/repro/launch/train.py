@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.data import DataPipeline, SyntheticLMDataset
 from repro.checkpoint import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.families import get_family
 from repro.optim import adamw, cosine_warmup
 from repro.parallel import plan_for, use_plan
@@ -63,6 +64,7 @@ def main() -> None:
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--model-parallel", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.replace(dtype=jnp.float32)  # CPU-friendly

@@ -11,10 +11,12 @@ hillclimb is "swap the plan", not "rewrite the model".
 """
 from repro.parallel.axes import (
     ShardingPlan,
+    batch_shard_map,
     current_plan,
     logical_spec,
     logical_sharding,
     shard,
+    under_plan,
     use_plan,
     sanitize_spec,
 )
@@ -27,10 +29,12 @@ from repro.parallel.plans import (
 
 __all__ = [
     "ShardingPlan",
+    "batch_shard_map",
     "current_plan",
     "logical_spec",
     "logical_sharding",
     "shard",
+    "under_plan",
     "use_plan",
     "sanitize_spec",
     "BASE_RULES",

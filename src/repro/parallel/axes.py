@@ -14,8 +14,9 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import jax
 import numpy as np
@@ -52,6 +53,19 @@ def use_plan(plan: ShardingPlan | None):
         yield plan
     finally:
         _STATE.plan = prev
+
+
+def under_plan(fn: Callable, plan: ShardingPlan | None) -> Callable:
+    """``fn`` run inside ``use_plan(plan)`` — wrap a function before
+    `jax.jit` so the model code it traces (`shard`, `batch_shard_map`)
+    sees the plan."""
+
+    @functools.wraps(fn)
+    def planned(*args, **kwargs):
+        with use_plan(plan):
+            return fn(*args, **kwargs)
+
+    return planned
 
 
 def _axis_size(mesh: Mesh, axes) -> int:
@@ -130,3 +144,25 @@ def shard(x: jax.Array, *logical_axes: str | None) -> jax.Array:
         return x
     spec = logical_spec(np.shape(x), logical_axes, plan)
     return jax.lax.with_sharding_constraint(x, NamedSharding(plan.mesh, spec))
+
+
+def batch_shard_map(fn: Callable, x, *rest):
+    """``fn(x, *rest)``, run once per batch shard of the current plan.
+
+    ``x`` and every output split along dim 0 over the ``"batch"`` rule's
+    mesh axes; ``rest`` replicates.  For per-example computations XLA
+    cannot partition itself — a Mosaic kernel must sit inside a
+    `shard_map`.  Outside a plan, or where the batch rule spans one
+    device, this is ``fn(x, *rest)``.
+    """
+    plan = current_plan()
+    if plan is None:
+        return fn(x, *rest)
+    spec = logical_spec(np.shape(x), ("batch",) + (None,) * (np.ndim(x) - 1),
+                        plan)
+    axes = spec[0] if len(spec) else None
+    if axes is None:
+        return fn(x, *rest)
+    in_specs = (P(axes),) + (P(),) * len(rest)
+    return jax.shard_map(fn, mesh=plan.mesh, in_specs=in_specs,
+                         out_specs=P(axes), check_vma=False)(x, *rest)

@@ -45,7 +45,7 @@ from repro.core.pixel_model import PixelModel
 from repro.core.quant import QuantSpec, quantize_deploy
 from repro.models.mobilenetv2 import MNV2Config, apply_mnv2
 from repro.obs.metrics import counted_lru_cache
-from repro.parallel import vision_plan_for
+from repro.parallel import under_plan, vision_plan_for
 from repro.parallel.sharding_utils import batch_shardings
 from repro.serving.scheduler import ScheduledRequest, SlotEngine
 
@@ -89,8 +89,9 @@ def _jit_forward(forward, cfg: MNV2Config, mesh: Mesh | None,
     img = batch_shardings(
         jax.ShapeDtypeStruct((batch, h, w, 3), jnp.float32), plan)
     rep = NamedSharding(mesh, P())
-    return jax.jit(forward, in_shardings=(rep, rep, rep, img),
-                   out_shardings=rep)
+    # the plan runs the stem kernel per batch shard
+    return jax.jit(under_plan(forward, plan),
+                   in_shardings=(rep, rep, rep, img), out_shardings=rep)
 
 
 @counted_lru_cache("deploy_forward")

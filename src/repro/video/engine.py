@@ -71,7 +71,7 @@ from repro.models.mobilenetv2 import (
     apply_mnv2_stem,
 )
 from repro.obs.metrics import counted_lru_cache
-from repro.parallel import vision_plan_for
+from repro.parallel import under_plan, vision_plan_for
 from repro.parallel.sharding_utils import batch_shardings
 from repro.serving.scheduler import ScheduledRequest, SlotEngine
 from repro.video.delta import DeltaGate, DeltaGateConfig
@@ -219,11 +219,12 @@ def _stream_forward_for(cfg: MNV2Config, dcfg: DetectConfig,
         jax.ShapeDtypeStruct((batch, ho, wo, co), jnp.float32), plan)
     msk = batch_shardings(jax.ShapeDtypeStruct((batch,), jnp.bool_), plan)
     rep = NamedSharding(mesh, P())
-    # the stem comes back *sharded* (it feeds straight into next tick's
-    # cached-stem operand, same sharding — no per-tick gather/reshard);
-    # the decoded boxes/scores and effective rerun mask replicate to the
-    # host
-    return jax.jit(forward, in_shardings=(rep, rep, rep, rep, img, cach, msk),
+    # the plan runs the stem kernel per batch shard; the stem comes back
+    # *sharded* (it feeds straight into next tick's cached-stem operand,
+    # same sharding — no per-tick gather/reshard); the decoded
+    # boxes/scores and effective rerun mask replicate to the host
+    return jax.jit(under_plan(forward, plan),
+                   in_shardings=(rep, rep, rep, rep, img, cach, msk),
                    out_shardings=(cach, rep, rep, rep))
 
 

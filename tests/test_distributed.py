@@ -218,3 +218,43 @@ def test_grad_compression_under_sharding():
     """))
     assert res["has_ef"]
     assert res["last"] < res["first"]  # training advances under compression
+
+
+def test_pallas_conv_runs_per_batch_shard_under_a_plan():
+    """XLA cannot partition a Mosaic kernel, so under a sharding plan the
+    P²M conv kernel runs inside a shard_map over the batch axis: loss
+    and gradients equal the unsharded ones, and the traced program holds
+    the shard_map (on one device, outside a plan, it does not)."""
+    res = _run(textwrap.dedent("""
+        import json, jax, jax.numpy as jnp, numpy as np
+        from repro.core.p2m_conv import (P2MConvConfig, apply_p2m_conv_train,
+                                         init_p2m_conv, init_p2m_state)
+        from repro.launch.mesh import make_debug_mesh
+        from repro.parallel import use_plan, vision_plan_for
+
+        cfg = P2MConvConfig()
+        params = init_p2m_conv(jax.random.PRNGKey(0), cfg)
+        st = init_p2m_state(cfg)
+        imgs = jnp.asarray(np.random.default_rng(0).random((8, 20, 20, 3)),
+                           jnp.float32)
+
+        def loss(p):
+            y, _ = apply_p2m_conv_train(p, st, imgs, cfg, impl="pallas")
+            return (y ** 2).sum()
+
+        vg = jax.value_and_grad(loss)
+        ref_l, ref_g = jax.jit(vg)(params)
+        plain = "shard_map" in str(jax.make_jaxpr(vg)(params))
+        mesh = make_debug_mesh(8)
+        with use_plan(vision_plan_for(mesh)), mesh:
+            mapped = "shard_map" in str(jax.make_jaxpr(vg)(params))
+            l, g = jax.jit(vg)(params)
+        gdiff = max(float(jnp.abs(a - b).max())
+                    for a, b in zip(jax.tree.leaves(ref_g), jax.tree.leaves(g)))
+        print(json.dumps({"plain": plain, "mapped": mapped,
+                          "dl": abs(float(l) - float(ref_l)),
+                          "gdiff": gdiff, "scale": float(ref_l)}))
+    """))
+    assert not res["plain"] and res["mapped"]
+    assert res["dl"] <= 1e-5 * res["scale"]
+    assert res["gdiff"] < 1e-4

@@ -223,9 +223,6 @@ def test_counted_lru_cache_counts_and_survives_reset():
 
 
 def test_autotune_counters_and_decision_record():
-    # lazy: repro.kernels.p2m_conv must not be the module's first repro
-    # import (core <-> kernels import cycle resolves via repro.core)
-    from repro.core.adc import ADCConfig  # noqa: F401
     from repro.kernels.p2m_conv import tune
 
     reg = default_registry()

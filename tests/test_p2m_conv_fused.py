@@ -301,3 +301,104 @@ def test_autotuned_conv_blocks_stay_correct():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     tune.cache_clear()
+
+
+def test_autotune_never_times_under_a_trace(monkeypatch):
+    """Tuning forced on: a jitted p2m_conv (forward and grad) serves the
+    static default, counted as ``autotune.traced_default``, and records no
+    timed decision; the same call made eagerly on concrete arrays tunes."""
+    from repro.obs.metrics import default_registry
+
+    monkeypatch.setenv("REPRO_P2M_AUTOTUNE", "1")
+    tune.cache_clear()
+    traced = default_registry().counter("autotune.traced_default")
+    t0 = traced.value
+    imgs, w, sh = _conv_data(1, 10, 10, 3, 5, seed=3)
+
+    def f(im):
+        return p2m_conv(im, w, sh, MODEL, ADC, "relu", 5, 5, True)
+
+    try:
+        jax.block_until_ready(jax.jit(f)(imgs))
+        jax.block_until_ready(jax.jit(jax.grad(lambda im: f(im).sum()))(imgs))
+        assert tune.decision_records() == []
+        assert traced.value - t0 >= 3  # conv fwd, conv fwd under grad, bwd
+        f(imgs)  # eager, concrete arrays: the tuner times candidates
+        (rec,) = tune.decision_records()
+        assert rec["kind"] == "conv" and rec["n_viable"] >= 1
+    finally:
+        tune.cache_clear()
+
+
+def test_autotune_refuses_to_time_tracers():
+    """`autotune` called while JAX traces raises instead of timing
+    tracing."""
+    tune.cache_clear()
+    key = ("traced_probe", 1)
+
+    def tunes_under_trace(x):
+        tune.autotune(key, [(1,), (2,)], lambda c: x * c[0], iters=1)
+        return x
+
+    try:
+        with pytest.raises(RuntimeError, match="under a trace"):
+            jax.jit(tunes_under_trace)(jnp.ones(2))
+        assert key not in tune._CACHE
+    finally:
+        tune.cache_clear()
+
+
+def test_mosaic_rejects_unlowerable_conv_geometries():
+    """Compiled (interpret=False), the geometries Mosaic cannot lower
+    raise a clear ValueError before lowering — stride != kernel, and the
+    DMA ring where k·C is off the 128-lane quantum — and the tuner takes
+    the ring off its menu there."""
+    from repro.kernels.p2m_conv.conv import mosaic_conv_error
+
+    assert mosaic_conv_error(5, 5, 3) is None
+    assert mosaic_conv_error(5, 5, 3, 0) is None
+    assert "128" in mosaic_conv_error(5, 5, 3, 2)
+    assert mosaic_conv_error(4, 4, 32, 2) is None  # k·C = 128 tiles
+    assert "stride" in mosaic_conv_error(5, 2, 3)
+
+    imgs, w, sh = _conv_data(1, 20, 20, 3, 5)
+    with pytest.raises(ValueError, match="stride 2 != kernel 5"):
+        p2m_conv_pallas(imgs, w, sh, kernel=5, stride=2, coeffs=COEFFS,
+                        interpret=False)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        p2m_conv_pallas(imgs, w, sh, kernel=5, stride=5, coeffs=COEFFS,
+                        pipeline_depth=2, interpret=False)
+    with pytest.raises(ValueError, match="stride 2 != kernel 5"):
+        tune.get_conv_blocks(1, 20, 20, 3, 8, 5, 2, COEFFS, "quant",
+                             enable=False, interpret=False)
+    tune.cache_clear()
+
+    def blocks_under_trace(x):
+        assert tune.get_conv_blocks(1, 20, 20, 3, 8, 5, 5, COEFFS, "quant",
+                                    enable=True, interpret=False) == (
+            None, None, 0)
+        return x
+
+    try:
+        jax.jit(blocks_under_trace)(jnp.ones(2))
+        assert tune.cache_info() == {}  # traced: nothing timed or cached
+    finally:
+        tune.cache_clear()
+
+
+def test_kernel_ops_imports_first():
+    """`repro.kernels.p2m_conv.ops` imports as a process's first repro
+    import (the core <-> kernels cycle resolves through module refs)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = {**os.environ, "PYTHONPATH": src, "JAX_PLATFORMS": "cpu"}
+    for mod in ("repro.kernels.p2m_conv.ops", "repro.core.bn_fold",
+                "repro.kernels.p2m_conv"):
+        out = subprocess.run([sys.executable, "-c", f"import {mod}"],
+                             env=env, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]

@@ -185,3 +185,34 @@ def test_shard_constraint_partitions_jitted_compute():
         y = jax.jit(lambda v: shard(v, "batch", None) * 2.0)(x)
     assert len(y.sharding.device_set) == 8
     np.testing.assert_allclose(np.asarray(y), np.asarray(x) * 2.0)
+
+
+def test_debug_mesh_axes_are_auto_so_plans_can_constrain():
+    """`jax.make_mesh` builds Explicit axes by default; the plans place
+    arrays with `with_sharding_constraint`, which needs Auto ones — so
+    every mesh the launch helpers build is Auto, and a constrained jit
+    under ``with use_plan(plan), mesh:`` lowers."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_debug_mesh
+
+    mesh = make_debug_mesh()
+    assert set(mesh.axis_types) == {AxisType.Auto}
+    plan = plan_for(mesh)
+    with use_plan(plan), mesh:
+        y = jax.jit(lambda x: shard(x, "batch", None) * 2)(jnp.ones((4, 2)))
+    np.testing.assert_array_equal(np.asarray(y), 2 * np.ones((4, 2)))
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    """Published v5e peaks, keyed by `device_kind`; an unknown kind is an
+    error, never a default."""
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND, chip_peaks
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert chip_peaks(PRODUCTION_DEVICE_KIND) is v5e
+    assert (v5e.flops_bf16, v5e.ops_int8) == (197e12, 393e12)
+    assert v5e.hbm_bytes_per_s == 819e9
+    assert v5e.ici_bytes_per_s * 8 == 1600e9  # 1,600 Gbit/s per chip
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks(jax.devices()[0].device_kind + " (unknown)")

@@ -106,3 +106,58 @@ def test_grad_accumulation_equivalence():
     diff = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
                         out1["params"], out2["params"])
     assert max(jax.tree.leaves(diff)) < 1e-5
+
+
+def test_compile_cache_dir_follows_env_else_fixed_repo_path(monkeypatch,
+                                                            tmp_path):
+    """`JAX_COMPILATION_CACHE_DIR` wins and no code sets another
+    directory; unset, the cache sits at a fixed, git-ignored path in the
+    checkout."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import (
+        REPO_CACHE_DIR,
+        compile_cache_dir,
+        enable_compile_cache,
+    )
+
+    repo = Path(__file__).resolve().parents[1]
+    assert REPO_CACHE_DIR == repo / ".jax_cache"
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache_dir() == str(tmp_path)
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # JAX reads env
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache_dir() == str(REPO_CACHE_DIR)
+
+
+@pytest.mark.parametrize("where", ["env", "repo"])
+def test_compile_cache_entries_land_in_the_chosen_dir(tmp_path, where):
+    """A process that enables the cache writes its compiled programs to
+    `JAX_COMPILATION_CACHE_DIR` when it is set, else to the in-repo
+    directory (pointed at ``tmp_path`` here, to keep the checkout clean)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=src, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    code = "import pathlib\nfrom repro.launch import compile_cache\n"
+    if where == "env":
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    else:
+        code += f"compile_cache.REPO_CACHE_DIR = pathlib.Path({str(tmp_path)!r})\n"
+    code += ("import jax, jax.numpy as jnp\n"
+             "compile_cache.enable_compile_cache()\n"
+             "jax.jit(lambda x: jnp.sin(x) * 3)(jnp.ones(5))"
+             ".block_until_ready()\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert any(p.name.startswith("jit__lambda") for p in tmp_path.iterdir())
