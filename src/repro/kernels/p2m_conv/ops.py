@@ -151,8 +151,9 @@ def _matmul_fwd_only(x, w, shift, model, adc, mode, interpret,
 
 
 def _p2m_fwd(x, w, shift, model, adc, mode, interpret, bwd_impl):
-    out, raw = _matmul_fwd_only(x, w, shift, model, adc, mode, interpret,
-                                want_raw=True)
+    with jax.named_scope("p2m_matmul_fwd"):
+        out, raw = _matmul_fwd_only(x, w, shift, model, adc, mode, interpret,
+                                    want_raw=True)
     return out, (x, w, shift, raw)
 
 
@@ -161,17 +162,18 @@ def _p2m_bwd(model, adc, mode, interpret, bwd_impl, res, g):
     adc = adc or _DEFAULT_ADC
     interpret = _resolve_interpret(interpret)
     coeffs = _coeff_tuple(model)
-    mask = epilogue_mask(raw, shift, mode=mode, full_scale=adc.full_scale)
-    g_eff = g.astype(jnp.float32) * mask
     # Reuse the forward-tuned blocks (cache hit — the fwd ran first).
     blocks = tune.get_matmul_blocks(x.shape[0], x.shape[1], w.shape[1],
                                     coeffs, mode, interpret=interpret)
-    gx, gw = p2m_backward(g_eff, w, x, coeffs,
-                          use_pallas=_use_pallas_bwd(bwd_impl, interpret),
-                          interpret=interpret, blocks=blocks)
-    gs = g_eff.sum(axis=0)
-    return (gx.astype(x.dtype), gw.astype(w.dtype),
-            gs.astype(jnp.asarray(shift).dtype))
+    with jax.named_scope("p2m_matmul_bwd"):
+        mask = epilogue_mask(raw, shift, mode=mode, full_scale=adc.full_scale)
+        g_eff = g.astype(jnp.float32) * mask
+        gx, gw = p2m_backward(g_eff, w, x, coeffs,
+                              use_pallas=_use_pallas_bwd(bwd_impl, interpret),
+                              interpret=interpret, blocks=blocks)
+        gs = g_eff.sum(axis=0)
+        return (gx.astype(x.dtype), gw.astype(w.dtype),
+                gs.astype(jnp.asarray(shift).dtype))
 
 
 p2m_matmul.defvjp(_p2m_fwd, _p2m_bwd)
@@ -250,9 +252,10 @@ def p2m_conv_jnp(images, w, shift, model: PixelModel,
 
 def _conv_fwd(images, w, shift, model, adc, mode, kernel, stride, interpret,
               bwd_impl, pipeline_depth):
-    out, raw = _conv_fwd_only(images, w, shift, model, adc, mode, kernel,
-                              stride, interpret, want_raw=True,
-                              pipeline_depth=pipeline_depth)
+    with jax.named_scope("p2m_conv_fwd"):
+        out, raw = _conv_fwd_only(images, w, shift, model, adc, mode, kernel,
+                                  stride, interpret, want_raw=True,
+                                  pipeline_depth=pipeline_depth)
     return out, (images, w, shift, raw)
 
 
@@ -265,24 +268,31 @@ def _conv_bwd(model, adc, mode, kernel, stride, interpret, bwd_impl,
     n = w.shape[1]
     m = raw.shape[0] * raw.shape[1] * raw.shape[2]
 
-    raw2d = raw.reshape(m, n)
-    mask = epilogue_mask(raw2d, shift, mode=mode, full_scale=adc.full_scale)
-    g_eff = g.reshape(m, n).astype(jnp.float32) * mask
+    with jax.named_scope("p2m_conv_bwd"):
+        raw2d = raw.reshape(m, n)
+        mask = epilogue_mask(raw2d, shift, mode=mode,
+                             full_scale=adc.full_scale)
+        g_eff = g.reshape(m, n).astype(jnp.float32) * mask
 
-    # Backward needs X values for the power factors: materialize the patch
-    # matrix once (zero-copy reshapes at stride == kernel; a gather
-    # otherwise).  Training-only cost — the forward stays patch-free.
-    x, im2col_vjp = jax.vjp(
-        lambda im: im2col_matrix(im, kernel, stride), images)
-    blocks = tune.get_matmul_blocks(x.shape[0], x.shape[1], w.shape[1],
-                                    coeffs, mode, interpret=interpret)
-    gx, gw = p2m_backward(g_eff, w, x, coeffs,
-                          use_pallas=_use_pallas_bwd(bwd_impl, interpret),
-                          interpret=interpret, blocks=blocks)
-    (gimages,) = im2col_vjp(gx.astype(x.dtype))  # col2im scatter
-    gs = g_eff.sum(axis=0)
-    return (gimages.astype(images.dtype), gw.astype(w.dtype),
-            gs.astype(jnp.asarray(shift).dtype))
+        # Backward needs X values for the power factors: materialize the
+        # patch matrix once (zero-copy reshapes at stride == kernel; a
+        # gather otherwise).  Training-only cost — the forward stays
+        # patch-free.
+        with jax.named_scope("im2col"):
+            x, im2col_vjp = jax.vjp(
+                lambda im: im2col_matrix(im, kernel, stride), images)
+        blocks = tune.get_matmul_blocks(x.shape[0], x.shape[1], w.shape[1],
+                                        coeffs, mode, interpret=interpret)
+        with jax.named_scope("dx_dw"):
+            gx, gw = p2m_backward(
+                g_eff, w, x, coeffs,
+                use_pallas=_use_pallas_bwd(bwd_impl, interpret),
+                interpret=interpret, blocks=blocks)
+        with jax.named_scope("col2im"):
+            (gimages,) = im2col_vjp(gx.astype(x.dtype))
+        gs = g_eff.sum(axis=0)
+        return (gimages.astype(images.dtype), gw.astype(w.dtype),
+                gs.astype(jnp.asarray(shift).dtype))
 
 
 p2m_conv.defvjp(_conv_fwd, _conv_bwd)

@@ -209,22 +209,47 @@ def apply_mnv2_stem(
     """
     new_state: dict[str, Any] = {}
     if cfg.variant == "p2m":
-        if p2m_deploy is not None:
-            x = apply_p2m_conv_deploy(p2m_deploy, images, cfg.p2m, pixel_model,
-                                      impl=p2m_impl)
-            new_state["stem"] = state["stem"]
-        else:
-            x, st = apply_p2m_conv_train(
-                params["stem"], state["stem"], images, cfg.p2m, pixel_model,
-                train=train, impl=p2m_impl
-            )
-            new_state["stem"] = st
+        with jax.named_scope("p2m_stem"):
+            if p2m_deploy is not None:
+                x = apply_p2m_conv_deploy(p2m_deploy, images, cfg.p2m,
+                                          pixel_model, impl=p2m_impl)
+                new_state["stem"] = state["stem"]
+            else:
+                x, st = apply_p2m_conv_train(
+                    params["stem"], state["stem"], images, cfg.p2m,
+                    pixel_model, train=train, impl=p2m_impl
+                )
+                new_state["stem"] = st
     else:
-        x = _conv(images, params["stem"]["w"], stride=2)
-        x, bn_st = _bn(x, params["stem"]["bn"], state["stem"]["bn"], train)
-        x = _relu6(x)
+        with jax.named_scope("stem"):
+            x = _conv(images, params["stem"]["w"], stride=2)
+            x, bn_st = _bn(x, params["stem"]["bn"], state["stem"]["bn"], train)
+            x = _relu6(x)
         new_state["stem"] = {"bn": bn_st}
     return x, new_state
+
+
+def _inverted_residual(x, blk: dict, bst: dict, t: int, stride: int,
+                       residual: bool, train: bool) -> tuple[jax.Array, dict]:
+    """One inverted-residual block: 1×1 expand (t ≠ 1), 3×3 depthwise,
+    1×1 project, plus the skip when ``residual``."""
+    nst: dict[str, Any] = {}
+    y = x
+    if t != 1:
+        y = _conv(y, blk["expand"]["w"])
+        y, st_ = _bn(y, blk["expand"]["bn"], bst["expand"]["bn"], train)
+        nst["expand"] = {"bn": st_}
+        y = _relu6(y)
+    y = _conv(y, blk["dw"]["w"], stride=stride, groups=y.shape[-1])
+    y, st_ = _bn(y, blk["dw"]["bn"], bst["dw"]["bn"], train)
+    nst["dw"] = {"bn": st_}
+    y = _relu6(y)
+    y = _conv(y, blk["project"]["w"])
+    y, st_ = _bn(y, blk["project"]["bn"], bst["project"]["bn"], train)
+    nst["project"] = {"bn": st_}
+    if residual:
+        y = y + x
+    return y, nst
 
 
 def apply_mnv2_backbone(
@@ -248,33 +273,18 @@ def apply_mnv2_backbone(
     for t, c, n, s in cfg.block_schedule():
         for i in range(n):
             stride = s if i == 0 else 1
-            blk = params[f"block{bidx}"]
-            bst = state[f"block{bidx}"]
-            nst: dict[str, Any] = {}
-            y = x
-            if t != 1:
-                y = _conv(y, blk["expand"]["w"])
-                y, st_ = _bn(y, blk["expand"]["bn"], bst["expand"]["bn"], train)
-                nst["expand"] = {"bn": st_}
-                y = _relu6(y)
-            y = _conv(y, blk["dw"]["w"], stride=stride, groups=y.shape[-1])
-            y, st_ = _bn(y, blk["dw"]["bn"], bst["dw"]["bn"], train)
-            nst["dw"] = {"bn": st_}
-            y = _relu6(y)
-            y = _conv(y, blk["project"]["w"])
-            y, st_ = _bn(y, blk["project"]["bn"], bst["project"]["bn"], train)
-            nst["project"] = {"bn": st_}
-            if stride == 1 and cin == c:
-                y = y + x
-            x = y
-            new_state[f"block{bidx}"] = nst
+            with jax.named_scope(f"backbone/block{bidx}"):
+                x, new_state[f"block{bidx}"] = _inverted_residual(
+                    x, params[f"block{bidx}"], state[f"block{bidx}"], t,
+                    stride, stride == 1 and cin == c, train)
             bidx += 1
             cin = c
 
-    x = _conv(x, params["head"]["w"])
-    x, st_ = _bn(x, params["head"]["bn"], state["head"]["bn"], train)
+    with jax.named_scope("backbone/head"):
+        x = _conv(x, params["head"]["w"])
+        x, st_ = _bn(x, params["head"]["bn"], state["head"]["bn"], train)
+        x = _relu6(x)
     new_state["head"] = {"bn": st_}
-    x = _relu6(x)
     return x, new_state
 
 
@@ -296,8 +306,9 @@ def apply_mnv2(
     )
     x, new_state = apply_mnv2_backbone(params, state, x, cfg, train=train)
     new_state = {**stem_state, **new_state}
-    x = x.mean(axis=(1, 2))
-    logits = x @ params["fc"]["w"] + params["fc"]["b"]
+    with jax.named_scope("classifier"):
+        x = x.mean(axis=(1, 2))
+        logits = x @ params["fc"]["w"] + params["fc"]["b"]
     # (no "fc" entry in the state tree: the head is stateless, and the
     # output state must mirror the input structure exactly so one
     # sharding tree serves jit in_shardings and out_shardings alike)

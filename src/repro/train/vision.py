@@ -78,22 +78,24 @@ def make_vww_train_step(cfg: MNV2Config, optimizer: Optimizer,
         def loss_fn(params):
             logits, new_bn = apply_mnv2(params, state["bn"], images,
                                         cfg, pixel_model, train=True)
-            ce = softmax_ce(logits, labels)
-            acc = (logits.argmax(-1) == labels).mean()
+            with jax.named_scope("loss"):
+                ce = softmax_ce(logits, labels)
+                acc = (logits.argmax(-1) == labels).mean()
             return ce, (new_bn, acc)
 
         (loss, (new_bn, acc)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["params"])
 
         extras = dict(state.get("extras", {}))
-        if grad_compression == "int8_ef":
-            grads, extras["ef_error"] = compress_grads_int8_ef(
-                grads, extras.get("ef_error"))
-        elif grad_compression is not None:
-            raise ValueError(f"unknown grad_compression {grad_compression!r}")
-
-        new_params, new_opt = optimizer.update(grads, state["opt"],
-                                               state["params"], state["step"])
+        with jax.named_scope("optimizer"):
+            if grad_compression == "int8_ef":
+                grads, extras["ef_error"] = compress_grads_int8_ef(
+                    grads, extras.get("ef_error"))
+            elif grad_compression is not None:
+                raise ValueError(
+                    f"unknown grad_compression {grad_compression!r}")
+            new_params, new_opt = optimizer.update(
+                grads, state["opt"], state["params"], state["step"])
         new_state = {"params": new_params, "bn": new_bn, "opt": new_opt,
                      "step": state["step"] + 1}
         if extras:
