@@ -113,52 +113,125 @@ def p2m_bwd_dx_pallas(g, w, x, *, coeffs: tuple, block_m: int = 256,
 # ---------------------------------------------------------------------------
 
 
+def _dw_epilogue(acc, w, coeffs):
+    """Σ_i a_ij · i·|W|^∘(i-1) ⊙ T_j: ``acc`` is the ``(dx·bk, bn)`` stack
+    of the T_j, ``w`` the matching ``(bk, bn)`` weights."""
+    aw = jnp.abs(w.astype(jnp.float32))
+    bk = aw.shape[0]
+    total = jnp.zeros_like(aw)
+    wpow = jnp.ones_like(aw)                                 # |w|^(i-1)
+    for i in range(1, len(coeffs) + 1):
+        u_i = jnp.zeros_like(aw)
+        for j in range(1, len(coeffs[0]) + 1):
+            a_ij = float(coeffs[i - 1][j - 1])
+            if a_ij != 0.0:
+                u_i = u_i + a_ij * acc[(j - 1) * bk : j * bk, :]
+        total = total + float(i) * wpow * u_i
+        if i < len(coeffs):
+            wpow = wpow * aw
+    return total
+
+
+def _powers_t_dot(x, g, dx: int):
+    """[X, X∘X, …]ᵀ @ G for one tile: (rows, bk), (rows, bn) →
+    (dx·bk, bn)."""
+    return jax.lax.dot_general(
+        _power_concat(x.astype(jnp.float32), dx), g.astype(jnp.float32),
+        dimension_numbers=(((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _dw_kernel(x_ref, g_ref, w_ref, out_ref, acc_ref, *, coeffs, nm: int):
     mi = pl.program_id(2)
-    dw = len(coeffs)
-    dx = len(coeffs[0])
 
     @pl.when(mi == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)                      # (bm, bk)
-    g = g_ref[...].astype(jnp.float32)                      # (bm, bn)
-    xcat = _power_concat(x, dx)                              # (bm, dx·bk)
-    acc_ref[...] += jax.lax.dot_general(                     # (dx·bk, bn)
-        xcat, g,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _powers_t_dot(x_ref[...], g_ref[...], len(coeffs[0]))
 
     @pl.when(mi == nm - 1)
     def _epilogue():
-        aw = jnp.abs(w_ref[...].astype(jnp.float32))        # (bk, bn)
-        bk = aw.shape[0]
-        acc = acc_ref[...]
-        total = jnp.zeros_like(aw)
-        wpow = jnp.ones_like(aw)                             # |w|^(i-1)
-        for i in range(1, dw + 1):
-            u_i = jnp.zeros_like(aw)
-            for j in range(1, dx + 1):
-                a_ij = float(coeffs[i - 1][j - 1])
-                if a_ij != 0.0:
-                    u_i = u_i + a_ij * acc[(j - 1) * bk : j * bk, :]
-            total = total + float(i) * wpow * u_i
-            if i < dw:
-                wpow = wpow * aw
-        out_ref[...] = total
+        out_ref[...] = _dw_epilogue(acc_ref[...], w_ref[...], coeffs)
+
+
+def _dw_view_kernel(x_ref, g_ref, w_ref, out_ref, acc_ref, *, coeffs,
+                    nm: int):
+    """dW from the forward's image view: x_ref is the (bh, 1, Wo, kC)
+    block of kernel row ``dh``, g_ref the (bh, Wo, N) cotangent rows of
+    the same output pixels; acc_ref holds one T_j stack per kernel row."""
+    mi, dh = pl.program_id(0), pl.program_id(1)
+    k = acc_ref.shape[0]
+    bh, _, wo, kc = x_ref.shape
+
+    @pl.when((mi == 0) & (dh == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    x = x_ref[...].reshape(bh * wo, kc)
+    g = g_ref[...].reshape(bh * wo, g_ref.shape[-1])
+    acc_ref[dh] += _powers_t_dot(x, g, len(coeffs[0]))
+
+    @pl.when((mi == nm - 1) & (dh == k - 1))
+    def _epilogue():
+        for r in range(k):
+            out_ref[r] = _dw_epilogue(acc_ref[r], w_ref[r], coeffs)
+
+
+def _dw_from_view(g, w, view, *, coeffs, block_h, interpret):
+    """dW with X read through the forward's (mh_pad, k, Wo, kC) view.
+
+    The grid walks (row block, kernel row) over the first B·Ho = M / Wo
+    rows of the view, ``block_h`` rows a step.  ``block_h`` divides B·Ho
+    — by default the largest divisor up to 2048 / Wo rows, the forward
+    kernel's tile height — so the grid never reaches the view's padded
+    rows and nothing is padded."""
+    _, k, wo, kc = view.shape
+    m, n = g.shape
+    mh = m // wo
+    bh = block_h or next(d for d in range(min(mh, max(1, 2048 // wo)), 0, -1)
+                         if mh % d == 0)
+    if mh % bh:
+        raise ValueError(f"block_h {bh} does not divide B·Ho = {mh}")
+    nm = mh // bh
+    out = pl.pallas_call(
+        functools.partial(_dw_view_kernel, coeffs=coeffs, nm=nm),
+        grid=(nm, k),
+        in_specs=[
+            pl.BlockSpec((bh, 1, wo, kc), lambda mi, dh: (mi, dh, 0, 0)),
+            pl.BlockSpec((bh, wo, n), lambda mi, dh: (mi, 0, 0)),
+            pl.BlockSpec((k, kc, n), lambda mi, dh: (0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((k, kc, n), lambda mi, dh: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, kc, n), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((k, len(coeffs[0]) * kc, n), jnp.float32)],
+        interpret=interpret,
+    )(view, g.astype(jnp.float32).reshape(mh, wo, n),
+      w.astype(jnp.float32).reshape(k, kc, n))
+    return out.reshape(k * kc, n)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("coeffs", "block_m", "block_n", "block_k", "interpret"),
+    static_argnames=("coeffs", "block_m", "block_n", "block_k", "block_h",
+                     "interpret"),
 )
 def p2m_bwd_dw_pallas(g, w, x, *, coeffs: tuple, block_m: int = 256,
                       block_n: int = 128, block_k: int = 128,
-                      interpret: bool = False):
-    """dW of the raw basis sum. g: (M, N) masked cotangent, w: (K, N),
-    x: (M, K) → (K, N) float32."""
+                      block_h: int | None = None, interpret: bool = False):
+    """dW of the raw basis sum. g: (M, N) masked cotangent, w: (K, N) →
+    (K, N) float32.
+
+    ``x`` is the patch matrix (M, K), tiled by ``block_m/n/k``, or — at
+    ``stride == kernel`` — the forward's image view (mh_pad, k, Wo, k·C)
+    (`conv.image_view`), whose first M / Wo rows are the output pixel
+    rows; ``block_h`` of them, a divisor of M / Wo, per grid step
+    (`_dw_from_view`)."""
+    if x.ndim == 4:
+        return _dw_from_view(g, w, x, coeffs=coeffs, block_h=block_h,
+                             interpret=interpret)
     m, n = g.shape
     k = w.shape[0]
     dx = len(coeffs[0])
@@ -247,14 +320,16 @@ def epilogue_mask(raw, shift, *, mode: str, full_scale: float):
 
 
 def p2m_backward(g, w, x, coeffs, *, use_pallas: bool, interpret: bool = False,
-                 blocks: tuple[int, int, int] | None = None):
+                 blocks: tuple[int, int, int] | None = None, dw_view=None):
     """Dispatch (dX, dW): Pallas kernels on TPU (or forced interpret),
-    closed-form XLA otherwise."""
+    closed-form XLA otherwise.  ``dw_view``, the forward's image view
+    (Pallas only), is the dW kernel's operand in place of ``x``."""
     if use_pallas:
         bm, bn, bk = blocks or (256, 128, 128)
         gx = p2m_bwd_dx_pallas(g, w, x, coeffs=coeffs, block_m=bm,
                                block_n=bn, block_k=bk, interpret=interpret)
-        gw = p2m_bwd_dw_pallas(g, w, x, coeffs=coeffs, block_m=bm,
-                               block_n=bn, block_k=bk, interpret=interpret)
+        gw = p2m_bwd_dw_pallas(g, w, x if dw_view is None else dw_view,
+                               coeffs=coeffs, block_m=bm, block_n=bn,
+                               block_k=bk, interpret=interpret)
         return gx, gw
     return p2m_backward_jnp(g, w, x, coeffs)

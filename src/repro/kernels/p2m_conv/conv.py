@@ -341,11 +341,23 @@ def default_conv_blocks(b: int, ho: int, wo: int, n: int,
     return bh, bn
 
 
+def image_view(images, kernel: int, rows: int):
+    """The ``stride == kernel`` im2col matrix as a view of the cropped
+    images, ``(B·Ho, k, Wo, k·C)``, zero-padded to ``rows`` rows (§3.1 of
+    DESIGN.md).  The forward kernel reads it, and the dW kernel reads the
+    forward's copy (`backward.p2m_bwd_dw_pallas`)."""
+    b, h, w_dim, c = images.shape
+    k = kernel
+    ho, wo = conv_out_spatial(h, k, k), conv_out_spatial(w_dim, k, k)
+    a = images[:, : ho * k, : wo * k, :].reshape(b * ho, k, wo, k * c)
+    return jnp.pad(a, ((0, rows - b * ho), (0, 0), (0, 0), (0, 0)))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("kernel", "stride", "coeffs", "mode", "v_lsb",
                      "max_count", "block_h", "block_n", "want_raw",
-                     "interpret", "pipeline_depth"),
+                     "want_view", "interpret", "pipeline_depth"),
 )
 def p2m_conv_pallas(
     images,
@@ -361,6 +373,7 @@ def p2m_conv_pallas(
     block_h: int | None = None,
     block_n: int | None = None,
     want_raw: bool = False,
+    want_view: bool = False,
     interpret: bool = False,
     pipeline_depth: int = 0,
 ):
@@ -371,7 +384,9 @@ def p2m_conv_pallas(
     shift: (N,) BN counter pre-load in volts.
 
     ``want_raw=True`` additionally returns the pre-epilogue accumulation
-    (the training residual for the backward mask — see `backward.py`).
+    (the training residual for the backward mask — see `backward.py`),
+    and ``want_view=True`` (``stride == kernel`` only) the image view the
+    kernel read (`image_view`: the dW kernel's operand), after them.
 
     ``pipeline_depth``: 0 uses the grid-path kernels (Pallas's automatic
     pipeline); ≥ 2 switches to the manual double-buffered kernels, which
@@ -396,6 +411,9 @@ def p2m_conv_pallas(
     k, s = kernel, stride
     if not interpret and (err := mosaic_conv_error(k, s, c, pipeline_depth)):
         raise ValueError(err)
+    if want_view and s != k:
+        raise ValueError(f"want_view needs stride == kernel, got stride "
+                         f"{s}, kernel {k}")
     ho = conv_out_spatial(h, k, s)
     wo = conv_out_spatial(w_dim, k, s)
     kc = k * c
@@ -426,10 +444,9 @@ def p2m_conv_pallas(
     common = dict(mode=mode, v_lsb=v_lsb, max_count=max_count)
     pipelined = pipeline_depth >= 2
     if s == k:
-        # Zero-copy implicit im2col: crop the valid region and view it as
-        # (B·Ho, k, Wo, k·C); the grid's k-dimension walks kernel rows.
-        a = images[:, : ho * k, : wo * k, :].reshape(mh, k, wo, kc)
-        x_arr = jnp.pad(a, ((0, mh_pad - mh), (0, 0), (0, 0), (0, 0)))
+        # Zero-copy implicit im2col: the grid's k-dimension walks kernel
+        # rows of the image view.
+        x_arr = image_view(images, k, mh_pad)
         if pipelined:
             kernel_fn = functools.partial(
                 _conv_kernel_fast_pipelined, k=k, depth=pipeline_depth,
@@ -518,9 +535,10 @@ def p2m_conv_pallas(
     def _unpad(o):
         return o[:mh, :, :n].reshape(b, ho, wo, n)
 
-    if want_raw:
-        return _unpad(outs[0]), _unpad(outs[1])
-    return _unpad(outs[0])
+    res = [_unpad(o) for o in outs]
+    if want_view:
+        res.append(x_arr)
+    return tuple(res) if len(res) > 1 else res[0]
 
 
 def im2col_slices(images, kernel: int, stride: int):
@@ -535,7 +553,7 @@ def im2col_slices(images, kernel: int, stride: int):
     wo = conv_out_spatial(w_dim, k, s)
     m = b * ho * wo
     if s == k:
-        a = images[:, : ho * k, : wo * k, :].reshape(b * ho, k, wo, k * c)
+        a = image_view(images, k, b * ho)
         for dh in range(k):
             yield a[:, dh].reshape(m, k * c)
         return

@@ -11,7 +11,11 @@ Tiers, all computing the same math (see `ref.py` for the oracle):
   closed form elsewhere) instead of re-differentiating the forward.
 * :func:`p2m_conv` — the fused implicit-im2col convolution (`conv.py`):
   NHWC images in, no HBM patch tensor, same custom-VJP treatment.  The
-  hot path for both training and deployment.
+  hot path for both training and deployment.  At ``stride == kernel``
+  with the Pallas backward, the forward keeps the image view its kernel
+  read, and the dW kernel reads that view: the weight gradient builds no
+  patch matrix either.  Only the input gradient (dX, which training does
+  not take) and the other routes read the im2col patch matrix.
 * mode="quant" uses an STE backward (gradient of the soft-clipped path).
 
 Forward Pallas calls route their block sizes through the autotuner
@@ -44,6 +48,7 @@ from repro.kernels.p2m_conv.conv import (
     p2m_conv_pallas,
 )
 from repro.kernels.p2m_conv.kernel import p2m_matmul_pallas
+from repro.obs.metrics import default_registry
 
 _DEFAULT_ADC = ADCConfig()
 
@@ -196,8 +201,9 @@ def p2m_conv(images, w, shift, model: PixelModel,
     patch tensor in either the ``stride == kernel`` fast path (zero-copy
     image view) or the general strided path (per-kernel-row VMEM bands).
 
-    Backward runs the premixed closed-form kernels (`backward.py`); the
-    col2im scatter back to image space is a pure reshape at
+    Backward runs the premixed closed-form kernels (`backward.py`); at
+    ``stride == kernel`` the Pallas dW kernel reads the forward's image
+    view.  The col2im scatter back to image space is a pure reshape at
     ``stride == kernel`` and an XLA scatter-add otherwise.
 
     ``pipeline_depth`` overrides the autotuner's depth axis (DESIGN.md
@@ -211,7 +217,8 @@ def p2m_conv(images, w, shift, model: PixelModel,
 
 def _conv_fwd_only(images, w, shift, model, adc, mode, kernel, stride,
                    interpret, want_raw: bool = False,
-                   pipeline_depth: int | None = None):
+                   pipeline_depth: int | None = None,
+                   want_view: bool = False):
     adc = adc or _DEFAULT_ADC
     interpret = _resolve_interpret(interpret)
     coeffs = _coeff_tuple(model)
@@ -235,6 +242,7 @@ def _conv_fwd_only(images, w, shift, model, adc, mode, kernel, stride,
         block_n=bn,
         pipeline_depth=depth,
         want_raw=want_raw,
+        want_view=want_view,
         interpret=interpret,
     )
 
@@ -252,21 +260,27 @@ def p2m_conv_jnp(images, w, shift, model: PixelModel,
 
 def _conv_fwd(images, w, shift, model, adc, mode, kernel, stride, interpret,
               bwd_impl, pipeline_depth):
+    # The Pallas dW kernel reads the forward's image view: keep it.
+    want_view = stride == kernel and _use_pallas_bwd(
+        bwd_impl, _resolve_interpret(interpret))
     with jax.named_scope("p2m_conv_fwd"):
-        out, raw = _conv_fwd_only(images, w, shift, model, adc, mode, kernel,
-                                  stride, interpret, want_raw=True,
-                                  pipeline_depth=pipeline_depth)
-    return out, (images, w, shift, raw)
+        out, raw, *view = _conv_fwd_only(
+            images, w, shift, model, adc, mode, kernel, stride, interpret,
+            want_raw=True, pipeline_depth=pipeline_depth,
+            want_view=want_view)
+    return out, (images, w, shift, raw, view[0] if view else None)
 
 
 def _conv_bwd(model, adc, mode, kernel, stride, interpret, bwd_impl,
               pipeline_depth, res, g):
-    images, w, shift, raw = res
+    images, w, shift, raw, view = res
     adc = adc or _DEFAULT_ADC
     interpret = _resolve_interpret(interpret)
     coeffs = _coeff_tuple(model)
     n = w.shape[1]
     m = raw.shape[0] * raw.shape[1] * raw.shape[2]
+    route = "patches" if view is None else "view"
+    default_registry().counter(f"p2m_conv.bwd_dw_{route}").inc()
 
     with jax.named_scope("p2m_conv_bwd"):
         raw2d = raw.reshape(m, n)
@@ -274,10 +288,12 @@ def _conv_bwd(model, adc, mode, kernel, stride, interpret, bwd_impl,
                              full_scale=adc.full_scale)
         g_eff = g.reshape(m, n).astype(jnp.float32) * mask
 
-        # Backward needs X values for the power factors: materialize the
-        # patch matrix once (zero-copy reshapes at stride == kernel; a
-        # gather otherwise).  Training-only cost — the forward stays
-        # patch-free.
+        # X's powers enter both gradients.  dW reads the forward's image
+        # view where it kept one (stride == kernel, Pallas backward), and
+        # the patch matrix otherwise; dX always reads the patch matrix
+        # (zero-copy reshapes at stride == kernel; a gather otherwise).
+        # A step that takes no image gradient, as training does, leaves
+        # the matrix to dX alone, and XLA drops the whole dX chain.
         with jax.named_scope("im2col"):
             x, im2col_vjp = jax.vjp(
                 lambda im: im2col_matrix(im, kernel, stride), images)
@@ -287,7 +303,7 @@ def _conv_bwd(model, adc, mode, kernel, stride, interpret, bwd_impl,
             gx, gw = p2m_backward(
                 g_eff, w, x, coeffs,
                 use_pallas=_use_pallas_bwd(bwd_impl, interpret),
-                interpret=interpret, blocks=blocks)
+                interpret=interpret, blocks=blocks, dw_view=view)
         with jax.named_scope("col2im"):
             (gimages,) = im2col_vjp(gx.astype(x.dtype))
         gs = g_eff.sum(axis=0)

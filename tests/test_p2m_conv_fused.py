@@ -31,7 +31,9 @@ from repro.kernels.p2m_conv import (
 )
 from repro.kernels.p2m_conv import tune
 from repro.kernels.p2m_conv.backward import epilogue_mask
+from repro.kernels.p2m_conv.conv import ceil_to, image_view
 from repro.kernels.p2m_conv.ops import _coeff_tuple
+from repro.obs.metrics import default_registry
 
 MODEL = default_pixel_model()
 ADC = ADCConfig()
@@ -50,6 +52,7 @@ GEOMETRIES = [
     (2, 10, 10, 3, 3, 6),    # stride > kernel (gaps)
     (1, 5, 5, 3, 5, 5),      # single output pixel
 ]
+FAST_GEOMETRIES = [g for g in GEOMETRIES if g[4] == g[5]]
 
 
 def _conv_data(b, h, w_dim, c, k, n=8, seed=0):
@@ -181,11 +184,76 @@ def test_fused_conv_gradients_match_jnp(b, h, w_dim, c, k, s, mode):
     def loss_jnp(im, ww, ss):
         return (p2m_conv_jnp(im, ww, ss, MODEL, ADC, mode, k, s) ** 2).sum()
 
+    route = default_registry().counter(
+        "p2m_conv.bwd_dw_" + ("view" if s == k else "patches"))
+    n0 = route.value
     g1 = jax.grad(loss_pallas, argnums=(0, 1, 2))(imgs, w, sh)
+    assert route.value == n0 + 1
     g2 = jax.grad(loss_jnp, argnums=(0, 1, 2))(imgs, w, sh)
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("b,h,w_dim,c,k,s", FAST_GEOMETRIES)
+@pytest.mark.parametrize("fwd_block_h", [1, 3], ids=["fwd_divides",
+                                                     "fwd_pads"])
+@pytest.mark.parametrize("block_h", [None, 1], ids=["one_block", "per_row"])
+def test_dw_from_image_view_matches_patch_dw(b, h, w_dim, c, k, s,
+                                             fwd_block_h, block_h):
+    """The dW kernel on the forward's (mh_pad, k, Wo, k·C) image view ≡
+    the closed form on the im2col matrix of the same images: with B·Ho a
+    multiple of the forward's block_h (an unpadded view) and not (zero
+    rows the dW grid must not reach), in one row block or one per row."""
+    imgs, w, _ = _conv_data(b, h, w_dim, c, k, seed=11)
+    mh = b * ((h - k) // s + 1)
+    wo = (w_dim - k) // s + 1
+    rng = np.random.default_rng(12)
+    g = jnp.asarray(rng.standard_normal((mh * wo, w.shape[1])), jnp.float32)
+    _, ref = p2m_backward_jnp(g, w, im2col_matrix(imgs, k, s), COEFFS)
+    view = image_view(imgs, k, ceil_to(mh, fwd_block_h))
+    assert (view.shape[0] > mh) == (fwd_block_h == 3)
+    gw = p2m_bwd_dw_pallas(g, w, view, coeffs=COEFFS, block_h=block_h,
+                           interpret=True)
+    np.testing.assert_allclose(np.asarray(gw), np.asarray(ref),
+                               rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("want_raw", [False, True])
+def test_fused_conv_forward_bitwise_with_view(want_raw):
+    """Keeping the image view for the backward leaves the forward's
+    outputs bitwise as they are: p2m_conv_pallas with and without
+    want_view, and p2m_conv's primal under a Pallas VJP, which keeps it."""
+    imgs, w, sh = _conv_data(2, 23, 19, 3, 5, seed=13)
+    kw = dict(kernel=5, stride=5, coeffs=COEFFS, mode="relu", block_h=3,
+              want_raw=want_raw, interpret=True)
+    plain = p2m_conv_pallas(imgs, w, sh, **kw)
+    *outs, view = p2m_conv_pallas(imgs, w, sh, want_view=True, **kw)
+    plain = plain if want_raw else (plain,)
+    for a, b_ in zip(plain, outs, strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+    mh = 2 * 4
+    np.testing.assert_array_equal(np.asarray(view),
+                                  np.asarray(image_view(imgs, 5,
+                                                        ceil_to(mh, 3))))
+    primal, _ = jax.vjp(lambda ww: p2m_conv(imgs, ww, sh, MODEL, ADC, "relu",
+                                            5, 5, True, "pallas"), w)
+    np.testing.assert_array_equal(np.asarray(primal), np.asarray(plain[0]))
+
+
+def test_bwd_dw_route_counters():
+    """One trace of a p2m_conv backward counts its dW route once: the
+    image view at stride == kernel with the Pallas backward, the patch
+    matrix at stride != kernel."""
+    view = default_registry().counter("p2m_conv.bwd_dw_view")
+    patches = default_registry().counter("p2m_conv.bwd_dw_patches")
+    for k, s, counter in [(5, 5, view), (5, 3, patches)]:
+        imgs, w, sh = _conv_data(1, 13, 13, 3, k, seed=14)
+        v0, p0 = view.value, patches.value
+        jax.make_jaxpr(jax.grad(lambda ww: p2m_conv(
+            imgs, ww, sh, MODEL, ADC, "relu", k, s, True, "pallas").sum()))(w)
+        assert counter.value - (v0 if counter is view else p0) == 1
+        assert view.value + patches.value == v0 + p0 + 1
 
 
 def test_fused_conv_quant_ste_gradient():

@@ -9,6 +9,7 @@ described inside a module fixture, never at import time: only one process
 may load the TPU library, and it keeps it until it exits.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,9 +22,11 @@ from repro.core.pixel_model import default_pixel_model
 from repro.kernels.p2m_conv import (
     p2m_bwd_dw_pallas,
     p2m_bwd_dx_pallas,
+    p2m_conv,
     p2m_conv_pallas,
     p2m_conv_pallas_gated,
 )
+from repro.kernels.p2m_conv.conv import ceil_to, default_conv_blocks
 from repro.kernels.p2m_conv.kernel import p2m_matmul_pallas
 from repro.kernels.p2m_conv.ops import _coeff_tuple
 from repro.kernels.rwkv_wkv.kernel import wkv_pallas
@@ -95,19 +98,54 @@ def test_gated_stem_compiles_at_paper_geometry(spec):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("kernel_fn", [p2m_bwd_dx_pallas, p2m_bwd_dw_pallas],
-                         ids=["dX", "dW"])
-def test_backward_kernels_compile_at_train_size(spec, kernel_fn):
+def _view_rows() -> int:
+    """Rows of the forward's image view at train size: B·Ho padded to the
+    forward kernel's default block_h."""
+    bh, _ = default_conv_blocks(TRAIN_BATCH, HO, HO, CO, 3 * K * 3)
+    return ceil_to(TRAIN_BATCH * HO, bh)
+
+
+@pytest.mark.parametrize("kernel_fn,x_form",
+                         [(p2m_bwd_dx_pallas, "patches"),
+                          (p2m_bwd_dw_pallas, "patches"),
+                          (p2m_bwd_dw_pallas, "view")],
+                         ids=["dX", "dW", "dW-view"])
+def test_backward_kernels_compile_at_train_size(spec, kernel_fn, x_form):
     """M = 32·112² patch rows, K = 75, N = 8: one paper-geometry train
-    batch."""
+    batch, X as the (M, K) patch matrix or, for dW, as the forward's
+    (mh_pad, k, Wo, k·C) image view."""
     m = TRAIN_BATCH * HO * HO
 
     def fn(g, w, x):
         return kernel_fn(g, w, x, coeffs=COEFFS, interpret=False)
 
-    text = _compiled_text(fn, spec(m, CO), spec(K * K * 3, CO),
-                          spec(m, K * K * 3))
+    x = (spec(m, K * K * 3) if x_form == "patches"
+         else spec(_view_rows(), K, HO, K * 3))
+    text = _compiled_text(fn, spec(m, CO), spec(K * K * 3, CO), x)
     assert "tpu_custom_call" in text
+
+
+def test_weight_gradient_reads_the_image_view(spec):
+    """The compiled weight-only gradient of p2m_conv at stride == kernel,
+    train size: no instruction of the backward's im2col, one dW kernel,
+    and the dW kernel's image operand is the forward's padded view."""
+    from repro.core.adc import ADCConfig
+
+    def loss(w, x, sh):
+        return p2m_conv(x, w, sh, default_pixel_model(), ADCConfig(), "raw",
+                        K, S, False, "pallas").sum()
+
+    text = _compiled_text(jax.grad(loss), spec(K * K * 3, CO),
+                          spec(TRAIN_BATCH, IMG, IMG, 3), spec(CO))
+    instr = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+    names = [m.group(1) for m in map(instr.match, text.splitlines()) if m]
+    assert not [line for line in text.splitlines()
+                if "p2m_conv_bwd/im2col" in line and instr.match(line)]
+    dw = [n for n in names if re.fullmatch(r"p2m_bwd_dw_pallas(\.\d+)?", n)]
+    assert len(dw) == 1, dw
+    (call,) = [line for line in text.splitlines()
+               if re.match(rf"^\s*(?:ROOT\s+)?%?{re.escape(dw[0])} =", line)]
+    assert f"f32[{_view_rows()},{K},{HO},{K * 3}]" in call
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
