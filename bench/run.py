@@ -9,8 +9,9 @@ cell's shapes (set-up), measures for ``--seconds``, checks what the timed
 path produced against the plain reference, and prints one JSON line
 last on stdout.  ``--trace 0`` reports the cell's end-to-end metrics,
 ``--trace 1`` its per-layer metrics from a profiler trace of the window.
-Without a TPU, or with fewer chips than the cell needs, it exits 1 and
-prints no result.
+Without a TPU, with fewer chips than the cell needs, or when set-up
+finds that the program would not see the inputs the reference reads, it
+exits 1 and prints no result.
 """
 from __future__ import annotations
 
@@ -132,8 +133,12 @@ def main(argv=None) -> int:
         return 1
     sys.path.insert(0, str(harness.CHECKOUT / "src"))
     harness.enable_compile_cache()
-    result, checks = run_cell(cell, args.seed, args.seconds,
-                              bool(args.trace), device)
+    try:
+        result, checks = run_cell(cell, args.seed, args.seconds,
+                                  bool(args.trace), device)
+    except harness.BenchError as e:
+        print(f"[bench] {e}", file=sys.stderr)
+        return 1
     harness.emit(result, checks)
     return 0
 

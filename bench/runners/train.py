@@ -2,11 +2,13 @@
 compiles it (default matmul precision, SGD with momentum).
 
 Traffic parameters (``"kind": "train"``): ``batch``, ``distinct_batches``
-rendered from the seed on the host and cycled, ``checked_steps`` (the
-first steps, run in set-up through the window's own feed and call, that
-the reference follows) and ``in_flight`` (steps dispatched ahead of the
-host).  Each step's batch is placed on the device inside the window, as
-a job's input pipeline would place it.
+rendered from the seed on the host and cycled, ``images`` (``"uint8"``:
+8-bit codes, as decoded camera images are), ``checked_steps`` (the first
+steps, run in set-up through the window's own feed and call, that the
+reference follows) and ``in_flight`` (steps dispatched ahead of the
+host).  Each step's batch is placed on the device inside the window as
+a job's input pipeline would place it, as 8-bit images, and scaled to
+float32 there by a program of its own; the step gets the float32 batch.
 """
 from __future__ import annotations
 
@@ -19,9 +21,52 @@ from bench import harness, program, synth
 from bench.tracing import capture
 
 
+SCALE = np.float32(1 / 255)
+
+
+def scale_images(codes):
+    """8-bit codes to float32 images in [0, 1], on the device."""
+    import jax.numpy as jnp
+
+    return codes.astype(jnp.float32) * SCALE
+
+
+def host_images(batch: dict) -> dict:
+    """The batch with float32 images, as the step sees it: 8-bit codes
+    scaled by the same formula as `scale_images`."""
+    return dict(batch, images=batch["images"].astype(np.float32) * SCALE)
+
+
+def render(cfg: dict, tr: dict, seed: int) -> list[dict]:
+    """The distinct batches as the feed places them: 8-bit images, int32
+    labels."""
+    if tr["images"] != "uint8":
+        raise harness.BenchError(f"traffic images {tr['images']!r}: only "
+                                 f"'uint8' is fed")
+    batches = [synth.vww_batch(cfg["image_size"], tr["batch"], seed, i)
+               for i in range(tr["distinct_batches"])]
+    return [dict(b, images=np.round(b["images"] * 255).astype(np.uint8))
+            for b in batches]
+
+
 def make_batches(cfg: dict, tr: dict, seed: int) -> list[dict]:
-    return [synth.vww_batch(cfg["image_size"], tr["batch"], seed, i)
-            for i in range(tr["distinct_batches"])]
+    """The distinct batches as the step and the reference see them:
+    float32 images, int32 labels."""
+    return [host_images(b) for b in render(cfg, tr, seed)]
+
+
+def check_placed(placed, host) -> None:
+    """Set-up's agreement check: the float32 images the step got from the
+    feed equal, bit for bit, the host's copy that the reference reads."""
+    got = np.asarray(placed)
+    if got.dtype != host.dtype or got.shape != host.shape:
+        raise harness.BenchError(f"the feed's images are {got.dtype}"
+                                 f"{got.shape}, the host's {host.dtype}"
+                                 f"{host.shape}")
+    off = int(np.count_nonzero(got.view(np.uint32) != host.view(np.uint32)))
+    if off:
+        raise harness.BenchError(f"the feed's first batch differs from the "
+                                 f"host's copy in {off} of {got.size} values")
 
 
 def build(run):
@@ -41,10 +86,14 @@ def build(run):
 
 
 class Feed:
-    """Cycles the host batches, placing each on the device when asked."""
+    """Cycles the host batches, placing each on the device when asked and
+    scaling its 8-bit images there by `scale_images`, jitted on its own."""
 
     def __init__(self, batches, span):
+        import jax
+
         self.batches, self.span = batches, span
+        self.scale = jax.jit(scale_images)
         self.i, self.wait_s, self.calls = 0, 0.0, 0
 
     def __call__(self):
@@ -53,6 +102,7 @@ class Feed:
         t = time.perf_counter()
         with self.span("bench.feed"):
             b = jax.device_put(self.batches[self.i % len(self.batches)])
+            b = dict(b, images=self.scale(b["images"]))
         self.wait_s += time.perf_counter() - t
         self.calls += 1
         self.i += 1
@@ -63,12 +113,16 @@ def run(run):
     import jax
 
     cell, tr = run.cell, run.cell.traffic
-    batches = make_batches(cell.cfg, tr, run.seed)
+    placed = render(cell.cfg, tr, run.seed)
+    batches = [host_images(b) for b in placed]
     params, bn, step, state = build(run)
-    feed = Feed(batches, run.span)
+    feed = Feed(placed, run.span)
     losses, kept = [], {}
     for i in range(tr["checked_steps"]):
-        state, m = step(state, feed())
+        b = feed()
+        if i == 0:
+            check_placed(b["images"], batches[0]["images"])
+        state, m = step(state, b)
         losses.append(float(m["loss"]))
         if i == 0:
             kept["mu1"] = state["opt"]["mu"]
@@ -76,17 +130,23 @@ def run(run):
     feed.wait_s, feed.calls = 0.0, 0
 
     run.start_window()
-    pending, steps = [], 0
+    pending, steps, dispatch_s, wait_s = [], 0, 0.0, 0.0
     with capture(run) as trace:
         with run.span("bench.window"):
             t0 = time.perf_counter()
             while time.perf_counter() < t0 + run.seconds:
-                state, m = step(state, feed())
+                b = feed()
+                t = time.perf_counter()
+                with run.span("bench.step"):
+                    state, m = step(state, b)
+                dispatch_s += time.perf_counter() - t
                 steps += 1
                 pending.append(m["loss"])
                 if len(pending) > tr["in_flight"]:
+                    t = time.perf_counter()
                     with run.span("bench.wait"):
                         pending.pop(0).block_until_ready()
+                    wait_s += time.perf_counter() - t
             with run.span("bench.wait"):
                 jax.block_until_ready(state)
             t_end = time.perf_counter()
@@ -95,7 +155,12 @@ def run(run):
     window_losses = [float(x) for x in pending]
     data = {"trace": trace(), "steps": steps, "wall_s": t_end - t0,
             "batch": tr["batch"], "input_wait_s": feed.wait_s,
-            "feeds": feed.calls, "cfg": cell.cfg}
+            "feeds": feed.calls, "dispatch_s": dispatch_s, "cfg": cell.cfg}
+    print(f"[window] {steps} steps in {t_end - t0:.3f} s; host ms a step: "
+          f"feed {feed.wait_s / max(steps, 1) * 1e3:.3f}, step call "
+          f"{dispatch_s / max(steps, 1) * 1e3:.3f}, wait on step n-"
+          f"{tr['in_flight']} {wait_s / max(steps, 1) * 1e3:.3f}",
+          file=sys.stderr, flush=True)
     e2e = {"train_images_per_s": steps * tr["batch"] / (t_end - t0)}
     del state, step, pending
 
