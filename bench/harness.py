@@ -15,11 +15,13 @@ the name ``BENCHMARK.json`` gives it:
 """
 from __future__ import annotations
 
+import gc
 import importlib.util
 import json
 import math
 import os
 import sys
+import time
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -38,6 +40,48 @@ def process_age_s() -> float:
     with open("/proc/uptime") as f:
         uptime = float(f.read().split()[0])
     return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+
+
+class HostWaits:
+    """What may have kept the host from the run over a window: the
+    machine's steal time (``/proc/stat``, 10-ms ticks; omitted where it
+    cannot be read) and Python's garbage collections.  For stderr."""
+
+    def __init__(self):
+        self.gc_s: list[float] = []
+        self._gc_t0 = None
+
+    def _on_gc(self, phase, info):
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:
+            self.gc_s.append(time.perf_counter() - self._gc_t0)
+
+    @staticmethod
+    def _read() -> dict:
+        try:
+            with open("/proc/stat") as f:
+                steal = int(f.readline().split()[8])
+        except (OSError, IndexError, ValueError):
+            return {}
+        return {"machine_steal_ms": steal * 1e3 / os.sysconf("SC_CLK_TCK")}
+
+    def __enter__(self):
+        self._start = self._read()
+        gc.callbacks.append(self._on_gc)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self._on_gc)
+        self._end = self._read()
+
+    def describe(self) -> str:
+        parts = [f"{k} {self._end[k] - v:.1f}" for k, v in self._start.items()
+                 if k in self._end]
+        gc_ms = [s * 1e3 for s in self.gc_s]
+        parts.append(f"gc {len(gc_ms)} runs {sum(gc_ms):.1f} ms, max "
+                     f"{max(gc_ms, default=0.0):.1f}")
+        return ", ".join(parts)
 
 
 # ------------------------------------------------------------ spec lookup

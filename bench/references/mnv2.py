@@ -49,14 +49,30 @@ def stem_spatial(cfg: dict) -> int:
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
+def _pow2(k):
+    """2**k for whole k in [-126, 127], exactly, from its bits."""
+    return jax.lax.bitcast_convert_type((k + 127) << 23, jnp.float32)
+
+
+def round_to(a, dtype: str):
+    """``a`` (float32) rounded to the nearest value of the float format
+    ``dtype``, ties to even, subnormals kept, overflow not modelled.
+    Plain arithmetic, not a cast there and back: the TPU's compiler drops
+    such a pair as excess precision (on a v5e the round trip returned its
+    input unrounded), and the control would not be the precision named."""
+    info = jnp.finfo(dtype)
+    _, e = jnp.frexp(a)
+    k = jnp.clip(jnp.maximum(e - 1, info.minexp) - info.nmant, -126, 126)
+    return jnp.round(a * _pow2(-k)) * _pow2(k)
+
+
 def _rounder(operands: str):
     """Rounds a contraction's operands to ``operands`` on the forward
     pass; gradients pass straight through in float32 (a float8 cotangent
     would flush the small gradients to zero, which is no precision)."""
     if operands == "float32":
         return lambda a: a
-    return lambda a: a + jax.lax.stop_gradient(
-        a.astype(operands).astype(jnp.float32) - a)
+    return lambda a: a + jax.lax.stop_gradient(round_to(a, operands) - a)
 
 
 # ------------------------------------------------------------------ init

@@ -108,7 +108,7 @@ def run(run):
     picks = np.random.default_rng([seed, 0xF0]).integers(0, len(pool), n)
 
     run.start_window()
-    with capture(run) as trace:
+    with capture(run) as trace, harness.HostWaits() as waits:
         t0, t_end, due_abs, submit, start, answer = serve_window(
             run, door, pool, due, picks)
     run.end_window()
@@ -116,8 +116,10 @@ def run(run):
     ok = ~np.isnan(answer)
     lat = np.where(ok, answer, t_end) - due_abs
     s = engine.stats
-    print(f"[frames] {describe_launches(start, answer)}; evicted "
-          f"{s['evictions']}", file=sys.stderr, flush=True)
+    p50, p95, p99 = (harness.percentile(lat, q) * 1e3 for q in (50, 95, 99))
+    print(f"[frames] frame ms p50 {p50!r} p95 {p95!r} p99 {p99!r}; "
+          f"{describe_launches(start, answer)}; evicted {s['evictions']}; "
+          f"host {waits.describe()}", file=sys.stderr, flush=True)
     data = {
         "trace": trace(),
         "latency_s": lat,
@@ -129,7 +131,7 @@ def run(run):
         "cfg": cell.cfg,
     }
     e2e = {
-        "frame_p95_ms": harness.percentile(lat, 95) * 1e3,
+        "frame_p95_ms": p95,
         "frames_per_s": float(((answer <= t0 + run.seconds) & ok).sum())
         / run.seconds,
     }
@@ -159,9 +161,13 @@ def describe_launches(start, answer) -> str:
         return "no launch answered"
     wall = (steps[1] - steps[0]) * 1e3
     q = np.percentile(wall, [25, 50, 75, 95])
+    long = wall > 10 * q[1]
+    worst = int(wall.argmax())
     return (f"launches {fill.size} wall ms p25 {q[0]:.2f} p50 {q[1]:.2f} "
-            f"p75 {q[2]:.2f} p95 {q[3]:.2f} max {wall.max():.2f}; mean fill "
-            f"{fill.mean():.2f}")
+            f"p75 {q[2]:.2f} p95 {q[3]:.2f} max {wall[worst]:.2f} (launch "
+            f"{worst}, {steps[0, worst] - steps[0, 0]:.3f} s after the "
+            f"first); over 10x the median {int(long.sum())}, "
+            f"{wall[long].sum():.2f} ms; mean fill {fill.mean():.2f}")
 
 
 def reference_probs(cell, params, state, pool, operands="float32",

@@ -1,13 +1,16 @@
-"""Find the knee of a frames cell: the highest offered rate at which the
-queue does not grow over the window and nothing is evicted.
+"""Find the knee of a frames cell: the highest offered rate at which
+every frame is answered (none evicted, none failed) and the 95th
+percentile of the queue time stays under two mean launch times, so that
+no backlog builds.
 
     python bench/sweep.py --workload <frames cell> --seed <n> --seconds <s> \\
         --rates 100,200,300
 
 One process, one set-up; each rate gets a fresh engine on the same
 compiled program and the cell's open-loop schedule at that rate.  Prints
-one JSON line per rate, then the knee and 0.8 × the knee.  A cell's
-fixed rate is then written as a number into its traffic file.
+one JSON line per rate, then the knee (the highest rate below the
+first that fails) and 0.8 × the knee.  A cell's fixed rate is then
+written as a number into its traffic file.
 """
 from __future__ import annotations
 
@@ -29,28 +32,26 @@ def sweep_rate(run, drv, params, state, pool, rate: float) -> dict:
     n = int(round(rate * run.seconds))
     due = harness.fixed_gaps(n, run.seconds, run.seed)
     picks = np.random.default_rng([run.seed, 0xF0]).integers(0, len(pool), n)
-    depth = []
-    step = door.step
-
-    def traced_step():  # queue depth as each launch starts
-        depth.append(len(engine.queue))
-        return step()
-
-    door.step = traced_step
     t0, t_end, due_abs, submit, start, answer = drv.serve_window(
         run, door, pool, due, picks)
     ok = ~np.isnan(answer)
     lat = (np.where(ok, answer, t_end) - due_abs) * 1e3
-    third = max(1, len(depth) // 3)
-    grow = float(np.mean(depth[-third:]) - np.mean(depth[:third]))
+    s = engine.stats
+    launch_ms = s["wall_us"] / max(1, s["launches"]) / 1e3
+    queue_p95_ms = float(np.percentile((start - submit)[ok], 95) * 1e3) \
+        if ok.any() else float("inf")
     return {"rate_per_s": rate, "offered": n, "answered": int(ok.sum()),
-            "evicted": engine.stats["evictions"], "launches":
-            engine.stats["launches"], "queue_growth": grow,
+            "evicted": s["evictions"], "failures": s["failures"],
+            "launches": s["launches"],
+            "launch_ms": launch_ms, "queue_p95_ms": queue_p95_ms,
             "p50_ms": float(np.percentile(lat, 50)),
             "p95_ms": float(np.percentile(lat, 95)),
+            "p99_ms": float(np.percentile(lat, 99)),
+            "gen_lag_p95_ms": float(np.percentile(submit - due_abs, 95)
+                                    * 1e3),
             "drain_s": float(t_end - t0 - run.seconds),
             "launches_seen": drv.describe_launches(start, answer),
-            "sustained": engine.stats["evictions"] == 0 and grow < 1.0}
+            "sustained": bool(ok.all()) and queue_p95_ms < 2 * launch_ms}
 
 
 def main(argv=None) -> int:
@@ -79,8 +80,11 @@ def main(argv=None) -> int:
     for rate in (float(r) for r in args.rates.split(",")):
         rows.append(sweep_rate(run, drv, params, state, pool, rate))
         print(json.dumps(rows[-1]), flush=True)
-    good = [r["rate_per_s"] for r in rows if r["sustained"]]
-    knee = max(good) if good else None
+    knee = None  # the highest rate below the first that fails
+    for r in sorted(rows, key=lambda r: r["rate_per_s"]):
+        if not r["sustained"]:
+            break
+        knee = r["rate_per_s"]
     print(json.dumps({"workload": cell.name, "knee_per_s": knee,
                       "rate_0_8_knee": None if knee is None else 0.8 * knee,
                       "device": device}))
